@@ -77,7 +77,7 @@ bench-smoke:
 # CI bench gate: every lane in benchmarks/gates.json gets a fresh smoke
 # run and is gated against its committed baseline (ratio-based; per-lane
 # run spec, env, and slack all live in the manifest — including the
-# autotune sweep and the compiled-mode lane, which SKIPs on CPU hosts)
+# autotune sweep)
 bench-gate:
 	$(PY) benchmarks/check_regression.py --run-all
 

@@ -36,26 +36,20 @@ NEAR_TIE = 0.03   # keep the default within 3% of the best candidate
 GRIDS = {
     "rank_bn": (1024, 2048, 4096, 8192, 16384),
     "reduce_bn": (1024, 2048, 4096, 8192, 16384),
-    "search_bf": (128, 256, 512, 1024),
-    "span_bf": (128, 256, 512, 1024),
     "launch_pad_floor": (1, 2, 4, 8, 16),
 }
 GRIDS_SMOKE = {
     "rank_bn": (4096, 8192),
     "reduce_bn": (4096, 8192),
-    "search_bf": (128, 256),
-    "span_bf": (128, 256),
     "launch_pad_floor": (1, 4),
 }
 
-# fixture sizes (edges); the posting-window probe uses the same trie.
+# fixture sizes (edges).
 # 20k edges keeps the full interpret-mode sweep under ~10 min on the CPU
 # CI host while staying big enough that tile-size rankings are real; on
 # a TPU/GPU host (compiled kernels) bump toward the bench sizes.
 SWEEP_EDGES = 20_000
 SWEEP_EDGES_SMOKE = 2_048
-SWEEP_Q = 128
-SWEEP_Q_SMOKE = 32
 TIMING_REPS = 5
 TIMING_REPS_SMOKE = 3
 
@@ -149,169 +143,6 @@ def sweep_reduce_bn(arrs, grid, reps) -> dict:
     return candidates
 
 
-def sweep_search_bf(arrs, q, grid, reps) -> dict:
-    """Fused-descent bucket window: parity vs the layout-agnostic
-    ``rule_search_fused_ref`` at every block_f."""
-    import jax.numpy as jnp
-
-    from repro.core.synthetic import synthetic_search_queries
-    from repro.kernels.ref import rule_search_fused_ref
-    from repro.kernels.rule_search import rule_search_fused_pallas
-
-    queries, ant_len = synthetic_search_queries(arrs, q, 6)
-    qj, alj = jnp.asarray(queries), jnp.asarray(ant_len)
-    ec_np = arrs["edge_child"]
-    ep = jnp.asarray(arrs["edge_parent"])
-    ei = jnp.asarray(arrs["edge_item"])
-    ec = jnp.asarray(ec_np)
-    ecf = jnp.asarray(arrs["confidence"][ec_np])
-    esp = jnp.asarray(arrs["support"][ec_np])
-    elf = jnp.asarray(arrs["lift"][ec_np])
-    co = jnp.asarray(arrs["child_offsets"])
-    mf = int(arrs["max_fanout"])
-    ref = rule_search_fused_ref(ep, ei, ec, ecf, esp, elf, qj, alj)
-    candidates = {}
-    for bf in grid:
-        out = rule_search_fused_pallas(
-            co, ei, ec, ecf, esp, elf, qj, alj,
-            max_fanout=mf, interpret=True, block_f=bf,
-        )
-        for key in ("found", "node", "support", "confidence", "lift"):
-            _assert_bitwise(out[key], ref[key], f"search_bf={bf} {key}")
-        candidates[bf] = _median_us(
-            lambda: rule_search_fused_pallas(
-                co, ei, ec, ecf, esp, elf, qj, alj,
-                max_fanout=mf, interpret=True, block_f=bf,
-            )["lift"].block_until_ready(),
-            reps,
-        )
-    return candidates
-
-
-def sweep_span_bf(n_edges, q, grid, reps) -> dict:
-    """Span-descent edge window (compressed layout): parity vs the
-    full-table ``rule_search_span_ref`` oracle at every block_f, timed on
-    a chain-heavy fixture (the shape the compressed layout serves)."""
-    import jax.numpy as jnp
-
-    from repro.core.synthetic import (
-        device_trie_from_arrays, synthetic_chain_trie,
-        synthetic_search_queries,
-    )
-    from repro.kernels.ref import rule_search_span_ref
-    from repro.kernels.rule_search import rule_search_span_pallas
-
-    arrs = synthetic_chain_trie(n_edges, seed=5)
-    dt = device_trie_from_arrays(arrs, layout="compressed")
-    queries, ant_len = synthetic_search_queries(arrs, q, 8)
-    qj, alj = jnp.asarray(queries), jnp.asarray(ant_len)
-    ops_args = (
-        dt.child_offsets, dt.edge_item, dt.edge_child,
-        dt.edge_span, dt.edge_tail, dt.node_item,
-        dt.support, dt.confidence, dt.lift, qj, alj,
-    )
-    ref = rule_search_span_ref(
-        dt.edge_parent, dt.edge_item, dt.edge_child,
-        dt.edge_span, dt.edge_tail, dt.node_item,
-        dt.support, dt.confidence, dt.lift, qj, alj,
-    )
-    candidates = {}
-    for bf in grid:
-        out = rule_search_span_pallas(
-            *ops_args, max_fanout=dt.max_fanout, interpret=True,
-            block_f=bf,
-        )
-        for key in ("found", "pos", "support", "confidence", "lift"):
-            _assert_bitwise(out[key], ref[key], f"span_bf={bf} {key}")
-        candidates[bf] = _median_us(
-            lambda: rule_search_span_pallas(
-                *ops_args, max_fanout=dt.max_fanout, interpret=True,
-                block_f=bf,
-            )["lift"].block_until_ready(),
-            reps,
-        )
-    return candidates
-
-
-def sweep_posting_window(arrs, reps) -> dict:
-    """Posting-layout crossover: time ``rules_with_pallas`` with the
-    window forced on and off at the fixture's edge count, parity between
-    both layouts AND the oracle.  The winning layout decides whether the
-    crossover threshold moves below the probe E or stays at the default.
-    """
-    import jax.numpy as jnp
-
-    from repro.kernels.item_index import (
-        POSTING_WINDOW_EDGES, rules_with_pallas,
-    )
-    from repro.kernels.ref import rules_with_ref
-
-    d2n = arrs["dfs_to_node"]
-    item_nodes = arrs["item_nodes"]
-    offsets = arrs["item_offsets"]
-    n = int(d2n.shape[0])
-    dfs_order = arrs["dfs_order"]
-    post_lo_raw = dfs_order[item_nodes].astype(np.int64)
-    post_hi_raw = post_lo_raw + arrs["subtree_size"][item_nodes].astype(
-        np.int64
-    )
-    seg = np.repeat(
-        np.arange(offsets.shape[0] - 1, dtype=np.int64), np.diff(offsets)
-    )
-    order = np.argsort(seg * (n + 1) + post_hi_raw, kind="stable")
-    cols = dict(
-        support=jnp.asarray(arrs["support"][d2n]),
-        confidence=jnp.asarray(arrs["confidence"][d2n]),
-        lift=jnp.asarray(arrs["lift"][d2n]),
-        depth=jnp.asarray(arrs["node_depth"][d2n], jnp.int32),
-        node_item=jnp.asarray(arrs["node_item"][d2n], jnp.int32),
-    )
-    post_lo = jnp.asarray(post_lo_raw, jnp.int32)
-    post_hi = jnp.asarray(post_hi_raw[order], jnp.int32)
-    n_items = offsets.shape[0] - 1
-    items = np.arange(min(16, max(n_items, 1)), dtype=np.int32)
-    plos = jnp.asarray(offsets[items], jnp.int32)
-    phis = jnp.asarray(offsets[items + 1], jnp.int32)
-    items_j = jnp.asarray(items)
-    mp = int(arrs["max_postings"])
-
-    args = (
-        cols["support"], cols["confidence"], cols["lift"],
-        cols["depth"], cols["node_item"], post_lo, post_hi,
-        plos, phis, items_j,
-    )
-    kw = dict(k=10, metric="confidence", min_depth=1, role="any")
-    rv, rp = rules_with_ref(*args, **kw)
-    candidates = {}
-    for window in (False, True):
-        kv, kp = rules_with_pallas(
-            *args, max_postings=mp, window=window, interpret=True, **kw
-        )
-        _assert_bitwise(kv, rv, f"window={window} values")
-        _assert_bitwise(kp, rp, f"window={window} positions")
-        candidates[window] = _median_us(
-            lambda: rules_with_pallas(
-                *args, max_postings=mp, window=window, interpret=True,
-                **kw
-            )[0].block_until_ready(),
-            reps,
-        )
-    e = int(post_lo.shape[0])
-    # window wins at the probe E -> pull the crossover below it (pow2 of
-    # half the probe); full-array wins -> keep the committed default.
-    if candidates[True] < candidates[False]:
-        threshold = 1 << max(e // 2 - 1, 0).bit_length()
-        threshold = min(threshold, POSTING_WINDOW_EDGES)
-    else:
-        threshold = max(POSTING_WINDOW_EDGES, e)
-    return {
-        "candidates": {
-            "full_array": candidates[False], "window": candidates[True],
-        },
-        "threshold": int(threshold),
-    }
-
-
 def sweep_launch_pad_floor(arrs, grid, reps) -> dict:
     """Launch-pad floor: time a ragged-batch descent per floor (more pad
     rows, fewer distinct shapes), results bitwise-equal on real rows."""
@@ -319,7 +150,7 @@ def sweep_launch_pad_floor(arrs, grid, reps) -> dict:
 
     from repro.core.synthetic import synthetic_search_queries
     from repro.kernels.ops import dedup_query_rows
-    from repro.kernels.rule_search import rule_search_fused_pallas
+    from repro.kernels.rule_search import rule_search_fused
     from repro.kernels.tuning import tuning_overrides
 
     queries, ant_len = synthetic_search_queries(arrs, 11, 6, seed=3)
@@ -335,10 +166,9 @@ def sweep_launch_pad_floor(arrs, grid, reps) -> dict:
     def run(floor):
         with tuning_overrides(launch_pad_floor=floor):
             uq, ual, inv = dedup_query_rows(queries, ant_len)
-            out = rule_search_fused_pallas(
+            out = rule_search_fused(
                 co, ei, ec, ecf, esp, elf,
-                jnp.asarray(uq), jnp.asarray(ual),
-                max_fanout=mf, interpret=True,
+                jnp.asarray(uq), jnp.asarray(ual), max_fanout=mf,
             )
         lift = np.asarray(out["lift"])
         return lift if inv is None else lift[inv]
@@ -379,7 +209,6 @@ def main() -> None:
     backend = args.backend or jax.default_backend()
     grids = GRIDS_SMOKE if args.smoke else GRIDS
     n_edges = SWEEP_EDGES_SMOKE if args.smoke else SWEEP_EDGES
-    q = SWEEP_Q_SMOKE if args.smoke else SWEEP_Q
     reps = TIMING_REPS_SMOKE if args.smoke else TIMING_REPS
 
     t0 = time.time()
@@ -394,10 +223,6 @@ def main() -> None:
         ("rank_bn", lambda: sweep_rank_bn(arrs, grids["rank_bn"], reps)),
         ("reduce_bn",
          lambda: sweep_reduce_bn(arrs, grids["reduce_bn"], reps)),
-        ("search_bf",
-         lambda: sweep_search_bf(arrs, q, grids["search_bf"], reps)),
-        ("span_bf",
-         lambda: sweep_span_bf(n_edges, q, grids["span_bf"], reps)),
         ("launch_pad_floor",
          lambda: sweep_launch_pad_floor(
              arrs, grids["launch_pad_floor"], reps)),
@@ -419,25 +244,6 @@ def main() -> None:
         })
         print(f"# {knob}: chose {winner} (default {default}; "
               f"{default_us / candidates[winner]:.2f}x)", flush=True)
-
-    win = sweep_posting_window(arrs, reps)
-    chosen["posting_window_edges"] = win["threshold"]
-    full_us = win["candidates"]["full_array"]
-    window_us = win["candidates"]["window"]
-    best_us = min(full_us, window_us)
-    results.append({
-        "knob": "posting_window_edges",
-        "candidates_us": {
-            "full_array": full_us, "window": window_us,
-        },
-        "default": DEFAULTS.posting_window_edges,
-        "chosen": win["threshold"],
-        "default_us": full_us,
-        "best_us": best_us,
-        "speedup_best_vs_default": full_us / best_us,
-    })
-    print(f"# posting_window_edges: chose {win['threshold']} "
-          f"(full={full_us:.0f}us window={window_us:.0f}us)", flush=True)
 
     cfg = dataclasses.replace(DEFAULTS, **chosen).validate()
     if not args.no_write_table:

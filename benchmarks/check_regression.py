@@ -3,8 +3,8 @@
 Every gate lane lives in ``benchmarks/gates.json`` — one entry per lane
 naming its committed baseline, config-key fields, gated ratio metric(s),
 slack (``max_ratio``), and the command that produces a fresh ``--smoke``
-JSON.  Adding a lane (the autotune sweep, a compiled-mode lane) is a
-manifest edit, not new Python.
+JSON.  Adding a lane (the autotune sweep, say) is a manifest edit, not
+new Python.
 
 Every gate is RATIO-based so it tolerates hardware differences between
 the baseline machine and the CI runner: what is compared is a speedup
@@ -29,9 +29,7 @@ Two modes:
     clobbered), gate each against its committed baseline, print a
     per-lane pass/fail table — also appended as markdown to
     ``$GITHUB_STEP_SUMMARY`` when set — and exit non-zero on any
-    failure.  A ``requires: compiled`` lane that produced no JSON (the
-    runner printed its skip marker on a CPU-only host and exited 0)
-    reports SKIP, not FAIL.
+    failure.
 
 ``--fresh PATH [--baseline PATH] [--max-ratio R]``
     Back-compat single-lane mode: gate one already-produced JSON.  The
@@ -104,12 +102,6 @@ def check_lane(
     if baseline_path is None:
         baseline_path = os.path.join(REPO, lane["baseline"])
     if not os.path.exists(baseline_path):
-        if lane.get("allow_missing_baseline"):
-            print(
-                f"bench-gate[{name}]: no committed baseline at "
-                f"{lane['baseline']} for this backend — record-only pass"
-            )
-            return 0
         print(f"bench-gate: missing baseline {baseline_path}",
               file=sys.stderr)
         return 2
@@ -187,7 +179,6 @@ def lane_command(lane: dict, manifest: dict, fresh: str):
         ]
     cmd = [sys.executable, "-m", "benchmarks.run",
            "--only", run["only"], "--smoke"]
-    cmd += run.get("extra_args", [])
     for flag in manifest["json_flags"]:
         cmd += [flag, fresh if flag == run["json_flag"] else ""]
     return cmd
@@ -196,8 +187,7 @@ def lane_command(lane: dict, manifest: dict, fresh: str):
 def run_lane(name: str, lane: dict, manifest: dict, fresh_dir: str):
     """Run one lane's bench subprocess.
 
-    Returns (fresh_path, status, log): status is "ran" | "skip" |
-    "error".
+    Returns (fresh_path, status, log): status is "ran" | "error".
     """
     fresh = os.path.join(fresh_dir, f"{name}.json")
     cmd = lane_command(lane, manifest, fresh)
@@ -210,8 +200,6 @@ def run_lane(name: str, lane: dict, manifest: dict, fresh_dir: str):
     if proc.returncode != 0:
         return fresh, "error", proc.stdout + proc.stderr
     if not os.path.exists(fresh):
-        if lane.get("requires") == "compiled":
-            return fresh, "skip", proc.stdout
         return fresh, "error", (
             f"bench wrote no JSON at {fresh}\n{proc.stdout}{proc.stderr}"
         )
@@ -225,7 +213,7 @@ def write_step_summary(rows) -> None:
     with open(path, "a") as f:
         f.write("## Bench gate\n\n| lane | status |\n|---|---|\n")
         for name, status in rows:
-            icon = {"PASS": "✅", "SKIP": "⏭️"}.get(status, "❌")
+            icon = "✅" if status == "PASS" else "❌"
             f.write(f"| {name} | {icon} {status} |\n")
         f.write("\n")
 
@@ -240,11 +228,6 @@ def run_all(manifest: dict, only=None) -> int:
                 continue
             print(f"=== bench-gate lane: {name} ===", flush=True)
             fresh, status, log = run_lane(name, lane, manifest, fresh_dir)
-            if status == "skip":
-                print(f"bench-gate[{name}]: SKIP "
-                      f"(requires {lane.get('requires')})")
-                rows.append((name, "SKIP"))
-                continue
             if status == "error":
                 print(f"bench-gate[{name}]: bench run failed\n{log}")
                 rows.append((name, "FAIL"))
@@ -266,12 +249,9 @@ def run_all(manifest: dict, only=None) -> int:
 
 def detect_lane(manifest: dict, fresh_path: str):
     """Back-compat single-file mode: match the payload's bench kind to a
-    manifest lane (skipping gated-off ``requires`` lanes, whose bench
-    kind collides with their interpret-mode sibling)."""
+    manifest lane."""
     kind = load_payload(fresh_path).get("bench")
     for name, lane in manifest["lanes"].items():
-        if lane.get("requires"):
-            continue
         base = os.path.join(REPO, lane["baseline"])
         if os.path.exists(base) and \
                 load_payload(base).get("bench") == kind:

@@ -72,8 +72,8 @@ def paired_t_test(a: Sequence[float], b: Sequence[float]) -> Tuple[float, float]
 
 def bench_interpret() -> bool:
     """Interpret-vs-compiled mode for every bench lane's kernel calls —
-    ONE decision (``repro.kernels.ops.interpret_mode``), honoring the
-    ``REPRO_FORCE_INTERPRET`` override ``run.py --compiled`` sets."""
+    ONE decision (``repro.kernels.ops.interpret_mode``): compiled on a
+    TPU, interpreted elsewhere."""
     from repro.kernels.ops import interpret_mode
 
     return interpret_mode()

@@ -408,7 +408,7 @@ def bench_rule_search_kernels() -> List[Row]:
 
     from repro.kernels.ops import rule_search
     from repro.kernels.rule_search import (
-        rule_search_fused_pallas,
+        rule_search_fused,
         rule_search_pallas,
     )
 
@@ -456,9 +456,8 @@ def bench_rule_search_kernels() -> List[Row]:
                 "seed_full_2launch": lambda: rule_search(
                     None, qj, alj, edges=seed_edges
                 )["lift"].block_until_ready(),
-                "csr_fused_kernel": lambda: rule_search_fused_pallas(
-                    co, ei, ec, ecf, esp, elf, qj, alj,
-                    max_fanout=mf, interpret=interp,
+                "csr_fused_kernel": lambda: rule_search_fused(
+                    co, ei, ec, ecf, esp, elf, qj, alj, max_fanout=mf,
                 )["lift"].block_until_ready(),
                 "oracle_binsearch": lambda: batched_rule_search(
                     dt_seed, qj, alj

@@ -5,17 +5,17 @@ dry-run live in ``repro.launch.roofline`` (they are derived from compiled
 artifacts, not timed here); each KERNEL lane additionally reports its own
 achieved-bandwidth roofline figure (``roofline.kernel_roofline``).
 
-``--compiled`` runs the kernels compiled instead of interpreted (real
-hardware numbers on TPU/GPU hosts).  On a CPU-only host — where Pallas
-TPU kernels cannot compile — the flag prints a skip marker and exits 0,
-so the CI lane is a no-op until it runs somewhere with an accelerator.
+On a TPU the kernels compile (``kernels.ops.interpret_mode``); on a CPU
+host they run in interpret mode, and those timings are interpreter
+numbers, not device numbers.
 """
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import traceback
+
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import paper_figs
 
@@ -104,23 +104,8 @@ def main() -> None:
              "trace_event JSON to this path (plus a .metrics.txt dump); "
              "'' disables writing",
     )
-    parser.add_argument(
-        "--compiled", action="store_true",
-        help="run kernels compiled (TPU/GPU hosts); on a CPU-only host "
-             "prints a skip marker and exits 0",
-    )
     args = parser.parse_args()
-    if args.compiled:
-        import jax
-
-        if jax.default_backend() == "cpu":
-            print(
-                "# SKIP: --compiled needs a TPU/GPU backend "
-                "(Pallas TPU kernels cannot compile on cpu); "
-                "interpret-mode lanes still gate on CPU CI"
-            )
-            return
-        os.environ["REPRO_FORCE_INTERPRET"] = "0"
+    enable_compile_cache()
     paper_figs.SMOKE = args.smoke
     paper_figs.JSON_OUT = args.json_out
     paper_figs.JSON_OUT_TOPK = args.json_out_topk

@@ -1134,14 +1134,22 @@ def _dequantized_columns(trie: DeviceTrie):
     )
 
 
-def compressed_step(
-    trie: DeviceTrie,
+def span_step(
+    child_offsets: jax.Array,
+    edge_item: jax.Array,
+    edge_child: jax.Array,
+    edge_span: jax.Array,
+    edge_tail: jax.Array,
+    node_item: jax.Array,
+    max_fanout: int,
     pos: jax.Array,
     rem: jax.Array,
     ctail: jax.Array,
     items: jax.Array,
 ):
-    """One item-consumption step of the span-aware descent.
+    """One item-consumption step of the span-aware descent, over the
+    compressed columns themselves (``edge_child`` holds child DFS
+    positions).
 
     State per query column: ``pos`` (current DFS position), ``rem``
     (span steps left before the next CSR node), ``ctail`` (compressed id
@@ -1152,25 +1160,38 @@ def compressed_step(
     Returns the advanced ``(pos, rem, ctail, hit)`` — callers gate the
     state update on their own activity mask.
     """
-    n = trie.node_item.shape[0]
+    n = node_item.shape[0]
     in_span = rem > 0
     nxt = jnp.minimum(pos + 1, n - 1)
-    span_hit = in_span & (trie.node_item[nxt] == items)
-    j = bucket_edge_lookup(
-        trie.child_offsets, trie.edge_item, trie.max_fanout, ctail, items
-    )
+    span_hit = in_span & (node_item[nxt] == items)
+    j = bucket_edge_lookup(child_offsets, edge_item, max_fanout, ctail, items)
     edge_hit = (~in_span) & (j >= 0)
     jc = jnp.maximum(j, 0)
-    if trie.edge_child.shape[0]:
-        e_pos = trie.edge_child[jc]
-        e_span = trie.edge_span[jc]
-        e_tail = trie.edge_tail[jc]
+    if edge_child.shape[0]:
+        e_pos = edge_child[jc]
+        e_span = edge_span[jc]
+        e_tail = edge_tail[jc]
     else:  # single-node trie: no edges to gather from
         e_pos = e_span = e_tail = jnp.zeros_like(pos)
     pos = jnp.where(span_hit, pos + 1, jnp.where(edge_hit, e_pos, pos))
     rem = jnp.where(span_hit, rem - 1, jnp.where(edge_hit, e_span, rem))
     ctail = jnp.where(edge_hit, e_tail, ctail)
     return pos, rem, ctail, span_hit | edge_hit
+
+
+def compressed_step(
+    trie: DeviceTrie,
+    pos: jax.Array,
+    rem: jax.Array,
+    ctail: jax.Array,
+    items: jax.Array,
+):
+    """``span_step`` over a compressed ``DeviceTrie``."""
+    return span_step(
+        trie.child_offsets, trie.edge_item, trie.edge_child,
+        trie.edge_span, trie.edge_tail, trie.node_item, trie.max_fanout,
+        pos, rem, ctail, items,
+    )
 
 
 def compressed_descend(trie: DeviceTrie, queries: jax.Array):

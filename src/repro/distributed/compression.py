@@ -68,19 +68,6 @@ def compressed_psum(x: jax.Array, axis: str, mesh) -> jax.Array:
         acc = jax.lax.psum(q.astype(jnp.int32), axis)
         return acc.astype(jnp.float32) * scale
 
-    # version-portable shard_map (experimental → public namespace), same
-    # dance as distributed.trie_sharding._shard_map (not imported: the
-    # array_trie encoder depends on THIS module, so that would be a cycle)
-    try:
-        from jax.experimental.shard_map import shard_map as sm
-
-        wrapped = sm(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)
-    except (ImportError, TypeError):
-        try:
-            wrapped = jax.shard_map(body, mesh=mesh, in_specs=P(),
-                                    out_specs=P(), check_vma=False)
-        except TypeError:
-            wrapped = jax.shard_map(body, mesh=mesh, in_specs=P(),
-                                    out_specs=P())
-    return wrapped(x)
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
+    )(x)

@@ -46,7 +46,7 @@ and the per-device k-best lists / search verdicts merge with
   bit-identical to the single-device kernel;
 * a found-winner select for ``rule_search`` — at most one shard can
   complete a descent — plus a max-merge of the consequent-path Support
-  (the fused kernel's ``con_support`` output) so compound-consequent lift
+  (the descent's ``con_support`` output) so compound-consequent lift
   (paper Eq. 1-4) is re-assembled globally even when the consequent path
   lives on a different shard than the rule's main path.
 
@@ -85,10 +85,7 @@ from repro.kernels.ops import (
     dedup_query_rows,
 )
 from repro.kernels.rank import LANE, rank_merge, topk_rank_batch_pallas
-from repro.kernels.rule_search import (
-    rule_search_fused_pallas,
-    rule_search_span_pallas,
-)
+from repro.kernels.rule_search import rule_search_fused, rule_search_span
 
 _BIG = 2**30
 
@@ -109,25 +106,10 @@ class ShardFailure(TrieQueryError):
 
 
 def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (experimental → public namespace)."""
-    try:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-    except (ImportError, TypeError):
-        sm = jax.shard_map
-        try:
-            return sm(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        except TypeError:  # pragma: no cover - future signature drift
-            return sm(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-            )
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 # ----------------------------------------------------------------------
@@ -1033,7 +1015,7 @@ def _rules_with_sharded(
     ps, pr = P("data"), P()
     n_shards = int(mesh.shape["data"])
     # compressed plans carry no posting-ordered metric columns, so the
-    # consequent role runs through the membership kernel below (a pure
+    # consequent role runs through the membership ranking below (a pure
     # node_item == qitem self-hit), mirroring the single-device dispatch
     if role == "consequent" and layout != "compressed":
         def fn(psup, pconf, plif, pdep, plos, phis, gdelta):
@@ -1157,7 +1139,7 @@ def sharded_rules_with(
     vals = vals[inv_j]
     pos = pos[inv_j]
     # compressed consequent answers come back as DFS positions (the
-    # membership kernel's coordinate), like every other role there
+    # membership ranking's coordinate), like every other role there
     back = (
         plan.trie.g_item_nodes
         if role == "consequent" and plan.trie.layout != "compressed"
@@ -1166,19 +1148,16 @@ def sharded_rules_with(
     return {"values": vals, "node": _take_back(back, pos), "pos": pos}
 
 
-@functools.partial(
-    jax.jit, static_argnames=("mesh", "max_fanout", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("mesh", "max_fanout"))
 def _rule_search_sharded(
-    st: ShardedDeviceTrie, queries, ant_len,
-    *, mesh, max_fanout, interpret,
+    st: ShardedDeviceTrie, queries, ant_len, *, mesh, max_fanout,
 ):
     n_shards = int(mesh.shape["data"])
 
     def fn(co, ei, ec, ecf, esp, elf, l2g, queries, ant_len):
-        out = rule_search_fused_pallas(
+        out = rule_search_fused(
             co[0], ei[0], ec[0], ecf[0], esp[0], elf[0],
-            queries, ant_len, max_fanout=max_fanout, interpret=interpret,
+            queries, ant_len, max_fanout=max_fanout,
         )
         l2g1 = l2g[0]
         node_g = jnp.where(
@@ -1187,9 +1166,9 @@ def _rule_search_sharded(
             -1,
         )
         if n_shards == 1:
-            # single-shard mesh: the whole trie is local, so the fused
-            # kernel's in-kernel compound lift is already the global
-            # answer — no collective, no re-select
+            # single-shard mesh: the whole trie is local, so the
+            # descent's compound lift is already the global answer — no
+            # collective, no re-select
             return (
                 out["found"], node_g, out["confidence"],
                 out["support"], out["lift"],
@@ -1213,8 +1192,8 @@ def _rule_search_sharded(
         # the consequent-only walk may succeed on a DIFFERENT shard than
         # the main path; merge its Support (nonzero on <= 1 shard) and
         # re-run the Eq. 1-4 select globally.  For single-item
-        # consequents the winning shard's in-kernel lift IS the node
-        # lift, which is exactly what compound_lift's single branch reads.
+        # consequents the winning shard's lift IS the node lift, which
+        # is exactly what compound_lift's single branch reads.
         csup = jnp.max(gather(out["con_support"]), axis=0)
         seq_len = jnp.sum((queries >= 0).astype(jnp.int32), axis=1)
         single = (seq_len - ant_len) == 1
@@ -1235,17 +1214,17 @@ def _rule_search_sharded(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "mesh", "max_fanout", "interpret",
+        "mesh", "max_fanout",
         "n_transactions", "confidence_scale", "lift_scale",
     ),
 )
 def _rule_search_sharded_span(
     st: ShardedDeviceTrie, queries, ant_len,
-    *, mesh, max_fanout, interpret,
+    *, mesh, max_fanout,
     n_transactions=0, confidence_scale=1.0, lift_scale=1.0,
 ):
     """Compressed-layout twin of ``_rule_search_sharded``: every device
-    runs the span-aware descent kernel over its local span pool, then
+    runs the span-aware descent over its local span pool, then
     the identical found-winner merge + global Eq. 1-4 re-assembly (local
     DFS positions translate through the shard's ``l2g`` row, whose index
     space coincides with local position space)."""
@@ -1253,13 +1232,12 @@ def _rule_search_sharded_span(
 
     def fn(co, ei, epos, espan, etail, nit, sup, conf, lif, l2g,
            queries, ant_len):
-        out = rule_search_span_pallas(
+        out = rule_search_span(
             co[0], ei[0], epos[0], espan[0], etail[0],
             nit[0], sup[0], conf[0], lif[0],
             queries, ant_len, max_fanout=max_fanout,
             n_transactions=n_transactions,
             confidence_scale=confidence_scale, lift_scale=lift_scale,
-            interpret=interpret,
         )
         l2g1 = l2g[0]
         node_g = jnp.where(
@@ -1336,13 +1314,12 @@ def sharded_rule_search_batch(
         found, node, conf, sup, lift = _rule_search_sharded_span(
             plan.trie, jnp.asarray(queries), jnp.asarray(ant_len),
             mesh=plan.mesh, max_fanout=plan.trie.max_fanout,
-            interpret=_interpret(), **plan.trie._dequant(),
+            **plan.trie._dequant(),
         )
     else:
         found, node, conf, sup, lift = _rule_search_sharded(
             plan.trie, jnp.asarray(queries), jnp.asarray(ant_len),
             mesh=plan.mesh, max_fanout=plan.trie.max_fanout,
-            interpret=_interpret(),
         )
     out = {
         "found": found, "node": node,

@@ -1,9 +1,11 @@
-"""Pallas TPU kernels for the Trie-of-Rules hot spots.
+"""Device programs for the Trie-of-Rules hot spots (Pallas kernels where
+a kernel pays, XLA where the op is a few gathers per step).
 
 - ``support_count``      mining Step 1: MXU matmul support counting
 - ``rule_search``        paper Fig. 8-10: batched CSR bucket trie descent
+                         (an XLA gather loop; the tables stay in HBM)
 - ``rule_search_batch``  Q ragged rules canonicalized + searched in ONE
-                         fused launch (the serving-side batched entry)
+                         descent (the serving-side batched entry)
 - ``trie_reduce``        paper traversal: masked column reductions
 - ``top_k_rules``        segmented ranked extraction over the
                          DFS-contiguous layout (whole-trie or
@@ -17,8 +19,7 @@
 The shared Eq. 1-4 / interestingness math lives in ``metrics_inkernel`` —
 one implementation for every kernel AND its jnp oracle (``ref``).
 
-Static launch knobs (tile sizes, the posting-window crossover, the
-launch-pad floor) resolve at op-dispatch time from the per-backend
+Static launch knobs (tile sizes, the launch-pad floor) resolve at op-dispatch time from the per-backend
 ``tuning.KernelConfig`` registry — committed tables under
 ``benchmarks/tuning/`` (regenerate with ``make autotune``), historical
 constants when no table exists.

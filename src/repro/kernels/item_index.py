@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: batched item-scoped top-k over the inverted index.
+"""Batched item-scoped top-k over the inverted index.
 
 The paper frames the trie as a *knowledge extraction* structure; the
 analyst queries it answers are rarely one rule at a time — they are "every
@@ -15,48 +15,25 @@ answerable without walking paths:
 
       |{u : subtree_lo[u] <= p}| - |{u : subtree_hi[u] <= p}|
 
-  two binary searches over the item's posting slice (``post_lo`` is
-  DFS-ascending by construction; ``post_hi`` is sorted per item at index
-  build).  No per-node root-path walk, ever.
+  — the prefix sum of a difference array that has +1 at every posting
+  subtree start and -1 at every end.  No per-node root-path walk, ever.
 
-``rules_with_pallas`` runs Q item queries in ONE launch: grid
-``(Q, n_tiles)``, each query scoring the DFS-ordered metric columns
-through VMEM in ``block_n``-wide tiles (``KernelConfig.rank_bn`` by
-default), masking to its membership test
-(consequent / antecedent / any role), and maintaining a k-best buffer row
-via the same incremental-extraction + rank-merge machinery as the
-segmented rank kernel (``rank.kbest_update`` — ONE implementation, so tie
-order matches ``jax.lax.top_k`` everywhere).
-
-VMEM envelope — two statically-selected posting layouts:
-
-* **full-array** (default while the posting arrays fit): both ``[E]``
-  posting arrays map into VMEM each grid step and the binary search runs
-  on the query's ``[plo, phi)`` slice in place.  Cheapest at today's
-  sizes (a constant block the compiler hoists across grid steps), but
-  residency grows with E — ~8 MB at 1e6 nodes.
-* **per-query windows** (``window=True``, auto-selected once
-  ``E > POSTING_WINDOW_EDGES``): each query's posting slice is gathered
-  once XLA-side into a ``[Q, Wpad]`` stack
-  (``Wpad = ceil(max_postings / LANE) * LANE`` — the windowed analogue
-  of ``max_fanout`` bounding bucket scans) and the kernel maps only
-  ``2 x Wpad`` lanes per grid step.  This is what makes the 1e7-node
-  tier fit: residency is bounded by the longest posting list no matter
-  how large E grows.  The gathered stack lives in HBM; the
-  ``ops.rules_with`` wrappers dedup duplicate items before the launch
-  (identical items → bit-identical rows), so skewed traffic pays for U
-  unique windows, not Q.
-
-Both layouts are bit-identical (the tests sweep them); ``max_postings``
-MUST bound every queried slice length in window mode
-(``item_index_arrays`` emits it) — shorter truncates the slice.
+``rules_with_pallas`` runs Q item queries in ONE ranking launch.  The
+membership test is plain XLA (``item_membership``): each query's posting
+slice is gathered into a ``max_postings``-wide window, scattered into a
+``[Q, N + 1]`` difference array and prefix-summed — the posting arrays
+stay in HBM at any trie size, and no kernel gathers from a 1-D vector
+(which the TPU's Pallas lowering refuses).  The ``[Q, N]`` membership
+mask then rides into the segmented rank kernel
+(``rank.topk_rank_batch_pallas``) as a per-query node filter, so the
+ranking, tie order and k-best merge are exactly the prefix top-k's.
 
 The consequent-only role needs no range counting (membership is just
 ``node_item == item``); ``kernels.ops.rules_with`` routes it through the
 posting-ordered columns + ``rank.topk_rank_batch_pallas`` instead (a
-contiguous posting-range scan), keeping this kernel for the roles that
-need the laminar range-count.  Both paths return identical node order for
-overlapping queries (postings are DFS-sorted), which the tests assert.
+contiguous posting-range scan) on the plain layout.  Both paths return
+identical node order for overlapping queries (postings are DFS-sorted),
+which the tests assert.
 """
 from __future__ import annotations
 
@@ -64,99 +41,63 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-import numpy as np
-
-from .metrics_inkernel import dequantize_metrics, metric_pad_dtype, rank_score
-from .rank import LANE, _iota, kbest_update
-from .tuning import get_kernel_config
+from .rank import topk_rank_batch_pallas
 
 ROLES = ("consequent", "antecedent", "any")
 
-_BIG = 2**30
 
-# Default full-array posting residency ceiling: above this edge count the
-# 2 arrays x 4 B x E residency (4 MB at this threshold) would crowd VMEM,
-# so the windowed layout takes over.  Static, so the choice is part of the
-# compiled kernel.  Tunable: KernelConfig.posting_window_edges.
-POSTING_WINDOW_EDGES = 512 * 1024
+@functools.partial(jax.jit, static_argnames=("role", "max_postings"))
+def item_membership(
+    node_item: jax.Array,   # int32 [N] DFS-ordered consequent items
+    post_lo: jax.Array,     # int32 [E] posting subtree starts (asc/item)
+    post_hi: jax.Array,     # int32 [E] posting subtree ends (sorted/item)
+    plos: jax.Array,        # int32 [Q] posting-slice start per query
+    phis: jax.Array,        # int32 [Q] posting-slice end per query
+    items: jax.Array,       # int32 [Q] queried item per query
+    *,
+    role: str,
+    max_postings: int,
+) -> jax.Array:
+    """bool ``[Q, N]``: does DFS position ``p`` hold a rule involving
+    query ``q``'s item in ``role``?
 
-
-def _n_bsearch_steps(max_postings: int) -> int:
-    n = max(int(max_postings), 1)
-    return int(np.ceil(np.log2(n + 1))) + 1
-
-
-def _make_member_kernel(
-    k: int, kpad: int, metric: str, min_depth: int, role: str,
-    n_steps: int, p_width: int, windowed: bool, block_n: int,
-    n_transactions: int, confidence_scale: float, lift_scale: float,
-):
-    """Kernel body factory.  ``p_width`` is the posting operand's lane
-    width: the padded full-array length, or ``Wpad`` when ``windowed``
-    (then the search runs on ``[0, slice_len)`` of the query's window
-    instead of ``[plo, phi)`` of the shared arrays)."""
-
-    def kernel(
-        params_ref, post_lo_ref, post_hi_ref,
-        sup_ref, conf_ref, lift_ref, depth_ref, nitem_ref,
-        vals_ref, pos_ref,
-    ):
-        i = pl.program_id(1)
-
-        @pl.when(i == 0)
-        def _init():
-            vals_ref[...] = jnp.full_like(vals_ref[...], -jnp.inf)
-            pos_ref[...] = jnp.full_like(pos_ref[...], -1)
-
-        plo = jnp.int32(0) if windowed else params_ref[0, 0]
-        phi = params_ref[0, 1]
-        qitem = params_ref[0, 2]
-        # Quantized columns (compressed layout) ride their narrow storage
-        # dtype through HBM->VMEM and widen here, per tile.
-        sup, conf, lift = dequantize_metrics(
-            sup_ref[...][0], conf_ref[...][0], lift_ref[...][0],
-            n_transactions, confidence_scale, lift_scale,
+    ``max_postings`` (static) MUST bound every queried slice length
+    (``item_rank_arrays`` emits it); a shorter window truncates the
+    slice.  Positions are counted with a difference array per query —
+    +1 at each posting subtree start, -1 at each end, prefix-summed —
+    so the laminar range count is exact integer arithmetic."""
+    n = node_item.shape[0]
+    q = plos.shape[0]
+    items = jnp.asarray(items, jnp.int32)
+    self_hit = node_item[None, :] == items[:, None]
+    if role == "consequent":
+        return self_hit
+    e = post_lo.shape[0]
+    if e == 0:
+        cnt = jnp.zeros((q, n), jnp.int32)
+    else:
+        w = max(int(max_postings), 1)
+        widx = jnp.asarray(plos, jnp.int32)[:, None] + jnp.arange(
+            w, dtype=jnp.int32
+        )[None, :]
+        live = widx < jnp.asarray(phis, jnp.int32)[:, None]
+        safe = jnp.clip(widx, 0, e - 1)
+        # out-of-slice lanes scatter to column N + 1: past the end, dropped
+        lo = jnp.where(live, post_lo[safe], n + 1)
+        hi = jnp.where(live, post_hi[safe], n + 1)
+        rows = jnp.broadcast_to(
+            jnp.arange(q, dtype=jnp.int32)[:, None], (q, w)
         )
-        depth = depth_ref[...][0]
-        nitem = nitem_ref[...][0]
-        pos = _iota(block_n) + i * block_n
-        score = rank_score(metric, sup, conf, lift)
-
-        def count_le(arr_ref, x):
-            """|{j in [plo, phi) : arr[j] <= x}| for each lane of ``x``,
-            by fixed-step binary search (arr ascending on the slice,
-            ``_BIG`` beyond it in window mode)."""
-            arr = arr_ref[...][0]
-            lo = jnp.full((block_n,), plo, jnp.int32)
-            hi = jnp.full((block_n,), phi, jnp.int32)
-            for _ in range(n_steps):
-                mid = (lo + hi) // 2
-                midc = jnp.clip(mid, 0, p_width - 1)
-                v = arr[midc]
-                go = (mid < phi) & (v <= x)
-                lo = jnp.where(go, mid + 1, lo)
-                hi = jnp.where(go, hi, mid)
-            return lo - plo
-
-        self_hit = nitem == qitem
-        if role == "consequent":
-            member = self_hit
-        else:
-            # laminar range count: #(subtree_lo <= pos) - #(subtree_hi <= pos)
-            cnt = count_le(post_lo_ref, pos) - count_le(post_hi_ref, pos)
-            if role == "antecedent":
-                # strict ancestors only: the node's own posting entry
-                # always contains its own position — subtract it back out
-                member = (cnt - self_hit.astype(jnp.int32)) > 0
-            else:  # "any": consequent or anywhere on the path above
-                member = cnt > 0
-        valid = member & (depth >= min_depth)
-        score = jnp.where(valid, score, -jnp.inf)
-        kbest_update(vals_ref, pos_ref, score, pos, k, kpad)
-
-    return kernel
+        diff = jnp.zeros((q, n + 1), jnp.int32)
+        diff = diff.at[rows, lo].add(1, mode="drop")
+        diff = diff.at[rows, hi].add(-1, mode="drop")
+        cnt = jnp.cumsum(diff[:, :n], axis=1)
+    if role == "antecedent":
+        # strict ancestors only: the node's own posting entry always
+        # contains its own position — subtract it back out
+        return (cnt - self_hit.astype(jnp.int32)) > 0
+    return cnt > 0          # "any": consequent or anywhere on the path
 
 
 def rules_with_pallas(
@@ -176,7 +117,6 @@ def rules_with_pallas(
     min_depth: int = 1,
     role: str = "any",
     max_postings: int = 0,
-    window: bool | None = None,
     interpret: bool = False,
     block_n: int | None = None,
     n_transactions: int = 0,
@@ -191,133 +131,22 @@ def rules_with_pallas(
     ``"any"``.  Rows follow ``jax.lax.top_k`` order with ``(-inf, -1)``
     empty slots.  Absent items are expressed as empty posting slices
     (``plos[q] == phis[q]``) plus an item id no node carries.
-
-    ``window`` selects the posting layout (see module docstring);
-    ``None`` auto-picks: full-array residency while the edge count stays
-    within the active ``KernelConfig.posting_window_edges`` crossover,
-    per-query ``max_postings``-bounded windows beyond.  ``block_n``
-    (metric-column tile) resolves from ``KernelConfig.rank_bn`` when
-    None.  Both layouts — and every legal knob value — are bit-identical.
+    ``max_postings`` must bound every queried slice (see
+    ``item_membership``); ``block_n`` is the rank kernel's tile.
     """
     if role not in ROLES:
         raise ValueError(f"role {role!r} not in {ROLES}")
-    cfg = get_kernel_config()
-    if block_n is None:
-        block_n = cfg.rank_bn
-    if window is None:
-        window = post_lo.shape[0] > cfg.posting_window_edges
-    return _rules_with_impl(
-        support, confidence, lift, depth, node_item,
-        post_lo, post_hi, plos, phis, items,
-        k=int(k), metric=metric, min_depth=int(min_depth), role=role,
-        max_postings=int(max_postings), window=bool(window),
-        interpret=interpret, block_n=int(block_n),
-        n_transactions=int(n_transactions),
-        confidence_scale=float(confidence_scale),
-        lift_scale=float(lift_scale),
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "k", "metric", "min_depth", "role", "max_postings", "window",
-        "interpret", "block_n",
-        "n_transactions", "confidence_scale", "lift_scale",
-    ),
-)
-def _rules_with_impl(
-    support, confidence, lift, depth, node_item,
-    post_lo, post_hi, plos, phis, items,
-    *, k, metric, min_depth, role, max_postings, window, interpret,
-    block_n, n_transactions, confidence_scale, lift_scale,
-):
     n = support.shape[0]
     q = plos.shape[0]
-    if n == 0 or k <= 0 or q == 0:
-        return (
-            jnp.full((q, max(k, 0)), -jnp.inf, jnp.float32),
-            jnp.full((q, max(k, 0)), -1, jnp.int32),
-        )
-    kpad = k + (-k % LANE)
-    npad = -n % block_n
-
-    def pad_col(a, fill, dtype):
-        return jnp.pad(
-            a.astype(dtype), (0, npad), constant_values=fill
-        ).reshape(1, -1)
-
-    sup = pad_col(support, 0, metric_pad_dtype(support))
-    conf = pad_col(confidence, 0, metric_pad_dtype(confidence))
-    lif = pad_col(lift, 0, metric_pad_dtype(lift))
-    dep = pad_col(depth, -1, jnp.int32)
-    # -2 never equals a query item (absent queries are sanitized to -1)
-    nit = pad_col(node_item, -2, jnp.int32)
-
-    plos = jnp.asarray(plos, jnp.int32)
-    phis = jnp.asarray(phis, jnp.int32)
-    e = post_lo.shape[0]
-
-    params = jnp.zeros((q, LANE), jnp.int32)
-    if window:
-        # Per-query posting windows [Q, w_pad]: each query's slice
-        # gathered once XLA-side; lanes beyond the slice read _BIG
-        # (sorts after every real DFS position, so the in-window binary
-        # search never crosses it).
-        w_pad = max(int(max_postings) + (-int(max_postings) % LANE), LANE)
-        widx = plos[:, None] + jax.lax.broadcasted_iota(
-            jnp.int32, (q, w_pad), 1
-        )
-        if e == 0:
-            plo_arr = jnp.full((q, w_pad), _BIG, jnp.int32)
-            phi_arr = jnp.full((q, w_pad), _BIG, jnp.int32)
-        else:
-            wvalid = widx < phis[:, None]
-            wsafe = jnp.clip(widx, 0, e - 1)
-            plo_arr = jnp.where(
-                wvalid, post_lo.astype(jnp.int32)[wsafe], _BIG
-            )
-            phi_arr = jnp.where(
-                wvalid, post_hi.astype(jnp.int32)[wsafe], _BIG
-            )
-        p_width = w_pad
-        post_spec = pl.BlockSpec((1, w_pad), lambda qi, i: (qi, 0))
-        params = params.at[:, 1].set(jnp.maximum(phis - plos, 0))
-    else:
-        e_pad = max(e + (-e % LANE), LANE)
-        # padding past the live postings sorts after every real position
-        plo_arr = jnp.pad(
-            post_lo.astype(jnp.int32), (0, e_pad - e), constant_values=_BIG
-        ).reshape(1, -1)
-        phi_arr = jnp.pad(
-            post_hi.astype(jnp.int32), (0, e_pad - e), constant_values=_BIG
-        ).reshape(1, -1)
-        p_width = e_pad
-        post_spec = pl.BlockSpec((1, e_pad), lambda qi, i: (0, 0))
-        params = params.at[:, 0].set(plos).at[:, 1].set(phis)
-    params = params.at[:, 2].set(items.astype(jnp.int32))
-
-    nn = sup.shape[1]
-    grid = (q, nn // block_n)
-    col_spec = pl.BlockSpec((1, block_n), lambda qi, i: (0, i))
-    out_spec = pl.BlockSpec((1, kpad), lambda qi, i: (qi, 0))
-    vals, pos = pl.pallas_call(
-        _make_member_kernel(
-            k, kpad, metric, min_depth, role,
-            _n_bsearch_steps(max_postings), p_width, window, block_n,
-            n_transactions, confidence_scale, lift_scale,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, LANE), lambda qi, i: (qi, 0)),
-            post_spec, post_spec,
-            col_spec, col_spec, col_spec, col_spec, col_spec,
-        ],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((q, kpad), jnp.float32),
-            jax.ShapeDtypeStruct((q, kpad), jnp.int32),
-        ],
-        interpret=interpret,
-    )(params, plo_arr, phi_arr, sup, conf, lif, dep, nit)
-    return vals[:, :k], pos[:, :k]
+    member = item_membership(
+        node_item, post_lo, post_hi, plos, phis, items,
+        role=role, max_postings=int(max_postings),
+    )
+    return topk_rank_batch_pallas(
+        support, confidence, lift, depth,
+        jnp.zeros((q,), jnp.int32), jnp.full((q,), n, jnp.int32),
+        k=k, metric=metric, min_depth=min_depth, interpret=interpret,
+        block_n=block_n, n_transactions=n_transactions,
+        confidence_scale=confidence_scale, lift_scale=lift_scale,
+        member=member,
+    )

@@ -1,11 +1,11 @@
-"""Public jit'd wrappers over the Pallas kernels.
+"""Public jit'd wrappers over the Pallas kernels and XLA descents.
 
-Each op auto-selects ``interpret=True`` off-TPU (this container is CPU-only;
-interpret mode executes the kernel body in Python, which is how the kernels
-are validated here), and composes kernels into the paper-level semantics
-(e.g. compound-consequent lift = two descents, Eq. 1-4).  The auto-selection
-is overridable via ``REPRO_FORCE_INTERPRET`` (see ``interpret_mode``), which
-is how the compiled-mode bench lane and local debugging force either path.
+Each op compiles its Pallas kernels on a TPU and runs them in interpret
+mode everywhere else (interpret mode executes the kernel body as plain
+jnp, which is how the kernels are validated on CPU hosts), and composes
+kernels into the paper-level semantics (e.g. compound-consequent lift =
+two descents, Eq. 1-4).  ``REPRO_FORCE_INTERPRET`` overrides the choice
+off-TPU (see ``interpret_mode``); a TPU always compiles.
 """
 from __future__ import annotations
 
@@ -31,9 +31,9 @@ from .rank import topk_rank_batch_pallas, topk_rank_pallas
 from .ref import rules_with_ref, topk_rank_batch_ref, topk_rank_ref
 from .support_count import support_count_pallas
 from .rule_search import (
-    rule_search_fused_pallas,
+    rule_search_fused,
     rule_search_pallas,
-    rule_search_span_pallas,
+    rule_search_span,
 )
 from .trie_reduce import trie_reduce_pallas
 from .tuning import launch_pad
@@ -47,10 +47,11 @@ def interpret_mode() -> bool:
     """Whether ops launch their Pallas kernels in interpret mode.
 
     Default: interpret everywhere but TPU (interpret mode executes the
-    kernel body in Python — how the kernels run on CPU CI).  The
-    ``REPRO_FORCE_INTERPRET`` env var overrides the backend sniff in
-    either direction: truthy values (1/true/yes/on/interpret) force
-    interpret, falsy values (0/false/no/off/compiled) force compiled.
+    kernel body as plain jnp — how the kernels run on CPU CI).  The
+    ``REPRO_FORCE_INTERPRET`` env var overrides the backend sniff off
+    the TPU: truthy values (1/true/yes/on/interpret) force interpret,
+    falsy values (0/false/no/off/compiled) force compiled.  On a TPU a
+    truthy value is an error: it would silently time the interpreter.
     The decision is cached per (env value, backend), so flipping the env
     var mid-process takes effect on the next op call.
     """
@@ -63,6 +64,11 @@ def interpret_mode() -> bool:
     if env is not None and env.strip():
         val = env.strip().lower()
         if val in _TRUTHY:
+            if backend == "tpu":
+                raise ValueError(
+                    f"REPRO_FORCE_INTERPRET={env!r} on a TPU: the kernels "
+                    f"always compile there; unset it"
+                )
             mode = True
         elif val in _FALSY:
             mode = False
@@ -155,6 +161,14 @@ _RETRYABLE_MARKERS = (
     "out of memory",
 )
 
+# markers of a refusal by the TPU compiler (a kernel over its VMEM
+# budget, a program over the device's memory): the status reads
+# RESOURCE_EXHAUSTED, but the same program is refused on every retry
+_COMPILE_MARKERS = (
+    "compile permanent error",
+    "memory space vmem",
+)
+
 
 def is_retryable(exc: BaseException) -> bool:
     """Retryable-vs-fatal classification for the serve loop's retry path.
@@ -165,14 +179,18 @@ def is_retryable(exc: BaseException) -> bool:
     backend hits the same dead shard — the resilience ladder demotes
     instead of retrying).  Anything else is classified by message:
     backend ``RuntimeError``s carrying a transient status phrase
-    (RESOURCE_EXHAUSTED / UNAVAILABLE / ...) retry, all other errors are
-    treated as bugs and surface immediately.
+    (RESOURCE_EXHAUSTED / UNAVAILABLE / ...) retry, except refusals by
+    the compiler (``_COMPILE_MARKERS``: a VMEM overflow reads
+    RESOURCE_EXHAUSTED too, but no retry can fit it); all other errors
+    are treated as bugs and surface immediately.
     """
     if isinstance(exc, InvalidQueryError):
         return False
     if isinstance(exc, TransientBackendError):
         return True
     msg = str(exc)
+    if any(marker in msg for marker in _COMPILE_MARKERS):
+        return False
     return any(marker in msg for marker in _RETRYABLE_MARKERS)
 
 
@@ -504,8 +522,8 @@ def edge_metric_arrays(trie) -> Dict[str, jax.Array]:
     (DeviceTrie or FrozenTrie accepted).
 
     Also carries the CSR child-bucket index (``child_offsets`` +
-    ``max_fanout``) when the trie has one; the fused single-launch kernel
-    needs it, and the full-sweep kernel ignores it.
+    ``max_fanout``) when the trie has one; the bucket descent needs it,
+    and the full-sweep kernel ignores it.
 
     COMPRESSED tries return the span-table form instead (marked with
     ``"layout": "compressed"``): the compressed CSR + span edge columns
@@ -561,9 +579,9 @@ def rule_search(
 ) -> Dict[str, jax.Array]:
     """Batched rule search with full paper metrics (compound lift incl.).
 
-    With a CSR child-bucket index this is ONE fused kernel launch (bucket
-    descent + consequent walk + Eq. 1-4 lift in-kernel).  Without one
-    (seed layout) it falls back to two full-sweep launches.
+    With a CSR child-bucket index this is ONE jitted descent (bucket
+    binary search + consequent walk + Eq. 1-4 lift, an XLA gather loop).
+    Without one (seed layout) it falls back to two full-sweep launches.
 
     ``trie`` may also be a ``core.delta_trie.StreamingTrie`` — the
     frozen kernel then answers over the base and rows touching a
@@ -585,7 +603,6 @@ def rule_search(
         edges = edge_metric_arrays(trie)
     queries = jnp.asarray(queries, jnp.int32)
     ant_len = jnp.asarray(ant_len, jnp.int32)
-    interp = _interpret()
 
     if queries.shape[0] == 0:
         # Q == 0: nothing to search; avoid tracing a zero-grid kernel.
@@ -600,7 +617,7 @@ def rule_search(
     if edges.get("layout") == "compressed":
         out = _profiled(
             "rule_search",
-            lambda: rule_search_span_pallas(
+            lambda: rule_search_span(
                 edges["child_offsets"], edges["edge_item"],
                 edges["edge_pos"], edges["edge_span"], edges["edge_tail"],
                 edges["node_item"], edges["support"], edges["confidence"],
@@ -609,11 +626,10 @@ def rule_search(
                 n_transactions=edges["n_transactions"],
                 confidence_scale=edges["confidence_scale"],
                 lift_scale=edges["lift_scale"],
-                interpret=interp,
             ),
             rows=queries.shape[0], shape=queries.shape, live=live,
         )
-        # The span kernel reports DFS positions; the op-level contract is
+        # The span descent reports DFS positions; the op-level contract is
         # original node ids (same dict shape as the plain paths).
         node = _pos_to_node(out["found"], out["pos"], edges["dfs_to_node"])
         return {
@@ -627,18 +643,19 @@ def rule_search(
     if edges.get("child_offsets") is not None:
         out = _profiled(
             "rule_search",
-            lambda: rule_search_fused_pallas(
+            lambda: rule_search_fused(
                 edges["child_offsets"], edges["edge_item"],
                 edges["edge_child"], edges["edge_conf"], edges["edge_sup"],
                 edges["edge_lift"], queries, ant_len,
-                max_fanout=edges["max_fanout"], interpret=interp,
+                max_fanout=edges["max_fanout"],
             ),
             rows=queries.shape[0], shape=queries.shape, live=live,
         )
-        # con_support is kernel plumbing for the sharded merge, not part
+        # con_support is descent plumbing for the sharded merge, not part
         # of the op-level contract (keeps single/sharded dicts identical)
         return {k: v for k, v in out.items() if k != "con_support"}
 
+    interp = _interpret()
     full = _profiled(
         "rule_search",
         lambda: rule_search_pallas(
@@ -1021,7 +1038,7 @@ def rules_with(
     )
     plos, phis, qitems = _posting_slices(arrays["item_offsets"], items)
     # Duplicate-item dedup: identical (sanitized) items produce
-    # bit-identical result rows, and the membership kernel materializes a
+    # bit-identical result rows, and the membership mask gathers a
     # [Q, ~max_postings] posting window per query — running the launch
     # over the U unique items bounds that at [U, ...] and cuts compute on
     # skewed traffic; rows expand back via the inverse map afterwards.
@@ -1062,7 +1079,7 @@ def rules_with(
         back = arrays["item_nodes"]
     else:
         # The compressed layout has no posting-ordered column block, so
-        # its consequent role also runs here: the membership kernel's
+        # its consequent role also runs here: the membership mask's
         # consequent test is a pure node_item self-hit (the posting
         # arrays are operands but never read), and postings are
         # DFS-sorted, so the node order matches the fast path's.
@@ -1283,7 +1300,7 @@ def rule_search_batch(
     edges: Optional[Dict[str, jax.Array]] = None,
     strict: bool = False,
 ) -> Dict[str, jax.Array]:
-    """Search Q rules in ONE fused kernel launch.
+    """Search Q rules in ONE descent.
 
     The serving-side batched entry: ``queries`` is either a sequence of
     ``(antecedent, consequent)`` item-sequence pairs — canonicalized and
@@ -1291,12 +1308,12 @@ def rule_search_batch(
     already-canonical padded ``[Q, L]`` row matrix with ``ant_len``.
     Either way the whole batch dedups to its UNIQUE canonical rows
     host-side (``dedup_query_rows`` — skewed serving traffic otherwise
-    re-descends duplicates), descends in one ``pallas_call`` (the PR-1
-    CSR fused kernel), and scatters results back to the original Q rows.
+    re-descends duplicates), descends in one jitted program (the CSR
+    bucket descent), and scatters results back to the original Q rows.
     Bit-identical per row to looping ``rule_search`` over the queries.
 
     ``trie`` may also be a ``distributed.trie_sharding.ShardPlan`` — the
-    batch then descends shard_map-distributed (each device's fused kernel
+    batch then descends shard_map-distributed (each device's descent
     over its local subforest, found-winner merge + global compound-lift
     re-assembly), bit-identical to this single-device form.
 

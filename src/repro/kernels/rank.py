@@ -143,13 +143,14 @@ def kbest_update(vals_ref, pos_ref, score, pos, k: int, kpad: int):
 
 def _make_kernel(k: int, kpad: int, metric: str, min_depth: int,
                  block_n: int, n_transactions: int,
-                 confidence_scale: float, lift_scale: float):
-    def kernel(
-        params_ref, sup_ref, conf_ref, lift_ref, depth_ref,
-        vals_ref, pos_ref,
-    ):
+                 confidence_scale: float, lift_scale: float,
+                 masked: bool):
+    def kernel(params_ref, sup_ref, conf_ref, lift_ref, depth_ref, *refs):
         # grid = (Q, n_tiles): queries outer, DFS tiles inner, so each
         # query's k-best buffer accumulates across its own tile sweep.
+        # Per-query refs (params, member, outputs) arrive as [1, lanes]:
+        # the query axis is squeezed out of their blocks.
+        member_ref, vals_ref, pos_ref = refs if masked else (None, *refs)
         i = pl.program_id(1)
 
         @pl.when(i == 0)
@@ -169,6 +170,8 @@ def _make_kernel(k: int, kpad: int, metric: str, min_depth: int,
         pos = _iota(block_n) + i * block_n
         score = rank_score(metric, sup, conf, lift)
         valid = (pos >= lo) & (pos < hi) & (depth >= min_depth)
+        if masked:
+            valid = valid & (member_ref[...][0] != 0)
         score = jnp.where(valid, score, -jnp.inf)
         kbest_update(vals_ref, pos_ref, score, pos, k, kpad)
 
@@ -191,6 +194,7 @@ def topk_rank_batch_pallas(
     n_transactions: int = 0,
     confidence_scale: float = 1.0,
     lift_scale: float = 1.0,
+    member: jax.Array | None = None,   # [Q, N] per-query node filter
 ):
     """Top-k of EVERY DFS range ``[los[q], his[q])`` in one launch.
 
@@ -205,6 +209,10 @@ def topk_rank_batch_pallas(
     in-kernel via the static dequant params, which default to the fp32
     no-op.
 
+    ``member`` (optional, nonzero = eligible) further restricts each
+    query to a node subset — how ``item_index.rules_with_pallas`` ranks
+    its item-membership sets with this same kernel.
+
     ``block_n`` (nodes per tile) resolves from the active per-backend
     ``KernelConfig`` when None — resolution happens in this thin
     un-jitted shim so a table change is never baked into a stale trace.
@@ -212,7 +220,7 @@ def topk_rank_batch_pallas(
     if block_n is None:
         block_n = get_kernel_config().rank_bn
     return _topk_rank_batch_impl(
-        support, confidence, lift, depth, los, his,
+        support, confidence, lift, depth, los, his, member,
         k=k, metric=metric, min_depth=min_depth, interpret=interpret,
         block_n=int(block_n),
         n_transactions=int(n_transactions),
@@ -229,7 +237,7 @@ def topk_rank_batch_pallas(
     ),
 )
 def _topk_rank_batch_impl(
-    support, confidence, lift, depth, los, his,
+    support, confidence, lift, depth, los, his, member=None,
     *, k, metric, min_depth, interpret, block_n,
     n_transactions, confidence_scale, lift_scale,
 ):
@@ -256,31 +264,46 @@ def _topk_rank_batch_impl(
     # Clamping hi to N keeps every padding lane outside [lo, hi).
     los = jnp.maximum(jnp.asarray(los, jnp.int32), 0)
     his = jnp.minimum(jnp.asarray(his, jnp.int32), n)
-    params = jnp.zeros((q, LANE), jnp.int32)
-    params = params.at[:, 0].set(los).at[:, 1].set(his)
+    # Per-query operands are [Q, 1, lanes] with the query axis squeezed
+    # out of each block: a (1, lanes) block of a [Q, lanes] array would
+    # break the TPU's (8, 128) block-tiling rule for any Q > 1.
+    params = jnp.zeros((q, 1, LANE), jnp.int32)
+    params = params.at[:, 0, 0].set(los).at[:, 0, 1].set(his)
 
     nn = sup.shape[1]
     grid = (q, nn // block_n)
     col_spec = pl.BlockSpec((1, block_n), lambda qi, i: (0, i))
-    out_spec = pl.BlockSpec((1, kpad), lambda qi, i: (qi, 0))
+    row_spec = pl.BlockSpec(
+        (pl.Squeezed(), 1, LANE), lambda qi, i: (qi, 0, 0)
+    )
+    out_spec = pl.BlockSpec(
+        (pl.Squeezed(), 1, kpad), lambda qi, i: (qi, 0, 0)
+    )
+    in_specs = [row_spec, col_spec, col_spec, col_spec, col_spec]
+    operands = [params, sup, conf, lif, dep]
+    if member is not None:
+        in_specs.append(pl.BlockSpec(
+            (pl.Squeezed(), 1, block_n), lambda qi, i: (qi, 0, i)
+        ))
+        operands.append(jnp.pad(
+            member.astype(jnp.int32), ((0, 0), (0, npad))
+        ).reshape(q, 1, -1))
     vals, pos = pl.pallas_call(
         _make_kernel(
             k, kpad, metric, min_depth, block_n,
             n_transactions, confidence_scale, lift_scale,
+            member is not None,
         ),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, LANE), lambda qi, i: (qi, 0)),
-            col_spec, col_spec, col_spec, col_spec,
-        ],
+        in_specs=in_specs,
         out_specs=[out_spec, out_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((q, kpad), jnp.float32),
-            jax.ShapeDtypeStruct((q, kpad), jnp.int32),
+            jax.ShapeDtypeStruct((q, 1, kpad), jnp.float32),
+            jax.ShapeDtypeStruct((q, 1, kpad), jnp.int32),
         ],
         interpret=interpret,
-    )(params, sup, conf, lif, dep)
-    return vals[:, :k], pos[:, :k]
+    )(*operands)
+    return vals[:, 0, :k], pos[:, 0, :k]
 
 
 def topk_rank_pallas(

@@ -108,7 +108,7 @@ def rule_search_fused_ref(
     compound lift included (main walk + root-anchored consequent walk).
 
     Deliberately layout-agnostic — full-table matching, no CSR — so it
-    cross-checks the bucket-windowed descent against independent logic.
+    cross-checks the bucket descent against independent logic.
     """
     main = rule_search_ref(
         edge_parent, edge_item, edge_child,
@@ -153,13 +153,13 @@ def rule_search_span_ref(
     confidence_scale: float = 1.0,
     lift_scale: float = 1.0,
 ) -> Dict[str, jax.Array]:
-    """Ground truth for the COMPRESSED-layout span kernel: the same
+    """Ground truth for the COMPRESSED-layout span descent: the same
     ``(pos, rem, ctail)`` state machine, but CSR-node steps match against
     the FULL compressed edge table (broadcast compare on the compressed
-    parent-id column) instead of a bucket-windowed scan — independent
-    logic for the part the kernel optimizes.  Metric columns dequantize
+    parent-id column) instead of a bucket binary search — independent
+    logic for the part the descent optimizes.  Metric columns dequantize
     through the same shared ``dequantize_metrics``, so fp32 inputs keep
-    the oracle bit-identical to the span kernel AND to the plain fused
+    the oracle bit-identical to the span descent AND to the plain fused
     pair."""
     q, width = queries.shape
     n = node_item.shape[0]
@@ -399,7 +399,7 @@ def rules_with_ref(
     confidence_scale: float = 1.0,
     lift_scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Ground truth for the membership kernel: the same laminar
+    """Ground truth for the membership ranking: the same laminar
     range-count (``searchsorted`` on the posting slice) as a dense [Q, N]
     membership matrix, then batched ``lax.top_k``.  Bit-identical to
     ``item_index.rules_with_pallas`` including tie order."""
@@ -423,8 +423,8 @@ def rules_with_ref(
         member = self_hit
     else:
         # Laminar range count per (query, node) via numpy searchsorted on
-        # each query's posting slice — independent of the kernel's
-        # fixed-step in-VMEM binary search.  This reference is never
+        # each query's posting slice — independent of the op's
+        # difference-array count.  This reference is never
         # jitted, so the slice bounds are concrete.
         arr_lo = np.asarray(post_lo)
         arr_hi = np.asarray(post_hi)

@@ -1,53 +1,46 @@
-"""Pallas TPU kernels: batched Trie-of-Rules descent (the paper's search op).
+"""Batched Trie-of-Rules descent (the paper's search op).
 
-Three kernels share this module:
+Three entry points share this module:
 
-``rule_search_fused_pallas`` — the production path.  The edge table is laid
-out in CSR child buckets (``array_trie.FrozenTrie.freeze``): node ``p``'s
-outgoing edges are contiguous at ``child_offsets[p]:child_offsets[p+1]``,
-item-sorted.  Each descent step gathers only the active node's bucket,
-padded to a tile-aligned ``max_fanout`` window:
+``rule_search_fused`` — the production path on the plain layout.  The
+edge table is laid out in CSR child buckets (``array_trie.FrozenTrie.
+freeze``): node ``p``'s outgoing edges are contiguous at
+``child_offsets[p]:child_offsets[p+1]``, item-sorted.  Each descent step
+binary-searches only the active node's bucket
+(``array_trie.bucket_edge_lookup``, ``O(log max_fanout)`` gathers per
+query), and the consequent-only walk needed for compound lift (paper
+Eq. 1-4) runs in the SAME scan, so a full-metric search is one jitted
+program returning found/node/support/confidence/lift plus the
+consequent-path Support (``con_support`` — the sharded engine merges it
+across devices before re-assembling compound lift globally).
 
-    per step s:  start[q] = child_offsets[node[q]]
-                 match[q, f] = (f < fanout(node[q]))
-                             & (edge_item[start[q]+f] == queries[q, s])
-                 child[q]    = max_f( match ? edge_child[start[q]+f] : -1 )
+``rule_search_span`` — its compressed-layout (path-compressed span)
+twin.  The node axis is DFS pre-order position and maximal single-child
+runs are spans: kept edges carry ``(item, head position, interior step
+count, run-tail compressed id)`` and span interiors occupy NO bucket.
+The per-query state is ``(pos, rem, ctail)`` (``array_trie.span_step``).
+Metric columns are POSITION-indexed and may be quantized (int32 support
+counts / bf16 / int8); they widen once through
+``metrics_inkernel.dequantize_metrics``, so the unquantized fp32 path
+stays bit-identical to the plain descent.
 
-so the per-step work is O(max_fanout) per query instead of O(E).  Hub
-nodes (buckets wider than one BF tile — typically just the root) are
-handled by a chunked sweep over their window (the ``n_fan_chunks`` loop).
-The consequent-only walk needed for compound lift (paper Eq. 1-4) runs
-fused inside the SAME kernel body, so a full-metric ``rule_search`` is one
-``pallas_call`` launch returning found/node/support/confidence/lift plus
-the consequent-path Support (``con_support`` — the sharded engine merges
-it across devices before re-assembling compound lift globally).
+Both descents are XLA gather loops over the L query columns, not Pallas
+kernels: a descent reads a few entries per step from tables of E edges,
+so the tables stay in HBM at any trie size (a VMEM-resident edge table
+caps the trie at a few hundred thousand edges, and Pallas on TPU refuses
+gathers from 1-D vectors outright).
 
-``rule_search_pallas`` — the seed full-sweep kernel, kept as the benchmark
-baseline and as the fallback when no CSR offsets are available.  It
-broadcast-compares every query against the ENTIRE lex-sorted edge table at
-every step (O(E) compares per step, streamed through VMEM in BE-wide
-chunks), and returns per-node metrics only; compound lift needs a second
-consequent-only invocation by the ops wrapper.
+``rule_search_pallas`` — the seed full-sweep kernel, kept as the
+benchmark baseline and as the fallback when no CSR offsets are
+available.  It broadcast-compares every query against the ENTIRE
+lex-sorted edge table at every step (O(E) compares per step, streamed
+through VMEM in BE-wide chunks), and returns per-node metrics only;
+compound lift needs a second consequent-only invocation by the ops
+wrapper.
 
-``rule_search_span_pallas`` — the compressed-layout (PR 8) twin of the
-fused kernel.  On a path-compressed trie the node axis is DFS pre-order
-position and maximal single-child runs are spans: kept edges carry
-``(item, head position, interior step count, run-tail compressed id)``
-and span interiors occupy NO bucket.  The per-query descent state is
-``(pos, rem, ctail)`` — inside a span (``rem > 0``) the next pre-order
-position IS the single child so the probe is one gather of the
-DFS-ordered item column (no bucket scan at all); at a CSR node the
-bucket window scan mirrors the fused kernel's, chunked by the
-``span_bf`` tuning knob.  Metric columns are POSITION-indexed here (the
-compressed layout stores node columns, not edge gathers) and may be
-quantized (int32 support counts / bf16 / int8) — the kernel widens them
-once at the top of the body via ``metrics_inkernel.dequantize_metrics``,
-so only the narrow storage dtype crosses HBM->VMEM and the unquantized
-fp32 path stays bit-identical to the plain fused kernel.
-
-Metrics ride ON THE EDGES in the two plain kernels (edge_conf/edge_sup/
+Metrics ride ON THE EDGES in the plain layout (edge_conf/edge_sup/
 edge_lift are the child node's Step-3 annotations gathered at freeze
-time) and on the DFS-ordered node columns in the span kernel.
+time) and on the DFS-ordered node columns in the compressed layout.
 """
 from __future__ import annotations
 
@@ -57,15 +50,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .metrics_inkernel import (
-    compound_lift, dequantize_metrics, metric_pad_dtype,
-)
-from .tuning import get_kernel_config
+from repro.core.array_trie import bucket_edge_lookup, span_step
 
-BQ = 128    # queries per tile
+from .metrics_inkernel import compound_lift, dequantize_metrics
+
+BQ = 128    # queries per tile (full-sweep kernel)
 BE = 2048   # edge-table chunk per compare sweep (full-sweep kernel)
-BF = 128    # default fan-out tile: CSR bucket window granularity
-            # (fused kernel; tunable: KernelConfig.search_bf)
 
 
 def _make_kernel(width: int, n_chunks: int):
@@ -223,114 +213,19 @@ def rule_search_pallas(
 
 
 # ----------------------------------------------------------------------
-# fused CSR kernel: bucket descent + consequent walk + compound lift
+# plain layout: CSR bucket descent + consequent walk + compound lift
 # ----------------------------------------------------------------------
-def _make_fused_kernel(width: int, n_fan_chunks: int, e_pad: int,
-                       block_f: int):
-    def kernel(
-        q_ref, al_ref,
-        co_ref, ei_ref, ec_ref, econf_ref, esup_ref, elift_ref,
-        node_ref, ok_ref, conf_ref, sup_ref, lift_ref, csup_ref,
-    ):
-        bq = q_ref.shape[0]
-        qs = q_ref[...]
-        ant_len = al_ref[...][:, 0]
-        co = co_ref[...][0]
-        ei = ei_ref[...][0]
-        ec = ec_ref[...][0]
-        ecf = econf_ref[...][0]
-        esp = esup_ref[...][0]
-        elf = elift_ref[...][0]
-
-        def bucket_scan(nodes, items):
-            """Child + edge metrics for (nodes, items) by scanning only
-            each node's CSR bucket, ``block_f`` lanes at a time (chunked
-            for hub nodes)."""
-            start = co[nodes]
-            count = co[nodes + 1] - start
-            child = jnp.full((bq,), -1, jnp.int32)
-            b_conf = jnp.zeros((bq,), jnp.float32)
-            b_sup = jnp.zeros((bq,), jnp.float32)
-            b_lift = jnp.zeros((bq,), jnp.float32)
-            for f in range(n_fan_chunks):
-                offs = (
-                    jax.lax.broadcasted_iota(jnp.int32, (bq, block_f), 1)
-                    + f * block_f
-                )
-                valid = offs < count[:, None]
-                idx = jnp.clip(start[:, None] + offs, 0, e_pad - 1)
-                match = valid & (ei[idx] == items[:, None])
-                child = jnp.maximum(
-                    child, jnp.max(jnp.where(match, ec[idx], -1), axis=1)
-                )
-                b_conf = jnp.maximum(
-                    b_conf, jnp.max(jnp.where(match, ecf[idx], 0.0), axis=1)
-                )
-                b_sup = jnp.maximum(
-                    b_sup, jnp.max(jnp.where(match, esp[idx], 0.0), axis=1)
-                )
-                b_lift = jnp.maximum(
-                    b_lift, jnp.max(jnp.where(match, elf[idx], 0.0), axis=1)
-                )
-            return child, b_conf, b_sup, b_lift
-
-        # main walk state (full rule path)
-        node = jnp.zeros((bq,), jnp.int32)
-        ok = jnp.ones((bq,), jnp.bool_)
-        conf = jnp.ones((bq,), jnp.float32)
-        sup = jnp.zeros((bq,), jnp.float32)
-        nlift = jnp.zeros((bq,), jnp.float32)
-        # fused consequent-only walk state (root-anchored, Eq. 1-4 lift)
-        cnode = jnp.zeros((bq,), jnp.int32)
-        cok = jnp.ones((bq,), jnp.bool_)
-        csup = jnp.zeros((bq,), jnp.float32)
-
-        for s in range(width):
-            item = qs[:, s]
-            has_item = item >= 0
-            in_cons = s >= ant_len
-
-            active = has_item & ok
-            child, e_conf, e_sup, e_lift = bucket_scan(
-                jnp.where(active, node, 0), item
-            )
-            hit = child >= 0
-            ok = jnp.where(active, hit, ok)
-            node = jnp.where(active & hit, child, node)
-            conf = jnp.where(active & hit & in_cons, conf * e_conf, conf)
-            sup = jnp.where(active & hit, e_sup, sup)
-            nlift = jnp.where(active & hit, e_lift, nlift)
-
-            c_active = has_item & in_cons & cok
-            cchild, _, c_sup, _ = bucket_scan(
-                jnp.where(c_active, cnode, 0), item
-            )
-            chit = cchild >= 0
-            cok = jnp.where(c_active, chit, cok)
-            cnode = jnp.where(c_active & chit, cchild, cnode)
-            csup = jnp.where(c_active & chit, c_sup, csup)
-
-        found = ok & (node > 0)
-        seq_len = jnp.sum((qs >= 0).astype(jnp.int32), axis=1)
-        single = (seq_len - ant_len) == 1
-        con_sup = jnp.where(cok & (cnode > 0), csup, 0.0)
-        node_ref[...] = jnp.where(found, node, -1)[:, None]
-        ok_ref[...] = found.astype(jnp.int32)[:, None]
-        conf_ref[...] = jnp.where(found, conf, 0.0)[:, None]
-        sup_ref[...] = jnp.where(found, sup, 0.0)[:, None]
-        lift_ref[...] = compound_lift(
-            found, single, nlift, conf, con_sup
-        )[:, None]
-        # Consequent-path Support as its own output: the sharded engine
-        # merges it across devices (the consequent path may live on a
-        # DIFFERENT shard than the main path) before re-running the same
-        # compound_lift select globally.
-        csup_ref[...] = con_sup[:, None]
-
-    return kernel
+def _columns(queries):
+    """Scan inputs: one (item column, column index) pair per step."""
+    q, width = queries.shape
+    steps = jnp.broadcast_to(
+        jnp.arange(width, dtype=jnp.int32)[:, None], (width, q)
+    )
+    return queries.astype(jnp.int32).T, steps
 
 
-def rule_search_fused_pallas(
+@functools.partial(jax.jit, static_argnames=("max_fanout",))
+def rule_search_fused(
     child_offsets: jax.Array,  # int32 [N+1] CSR buckets over the edge table
     edge_item: jax.Array,      # int32 [E] item-sorted within each bucket
     edge_child: jax.Array,     # int32 [E]
@@ -339,230 +234,86 @@ def rule_search_fused_pallas(
     edge_lift: jax.Array,      # f32 [E]
     queries: jax.Array,        # int32 [Q, L]
     ant_len: jax.Array,        # int32 [Q]
-    max_fanout: int = 0,       # static: widest bucket (sizes the window)
-    interpret: bool = False,
-    block_f: int | None = None,
+    *,
+    max_fanout: int = 0,       # static: widest bucket (bounds the search)
 ):
-    """Single-launch rule search with full paper metrics (compound lift
-    included): CSR bucket descent + fused consequent-only walk.
-
-    ``block_f`` (bucket-window lanes per fan-out chunk) resolves from
-    the active per-backend ``KernelConfig`` when None.
-    """
-    if block_f is None:
-        block_f = get_kernel_config().search_bf
-    return _rule_search_fused_impl(
-        child_offsets, edge_item, edge_child, edge_conf, edge_sup,
-        edge_lift, queries, ant_len,
-        max_fanout=int(max_fanout), interpret=interpret,
-        block_f=int(block_f),
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("max_fanout", "interpret", "block_f")
-)
-def _rule_search_fused_impl(
-    child_offsets, edge_item, edge_child, edge_conf, edge_sup,
-    edge_lift, queries, ant_len, *, max_fanout, interpret, block_f,
-):
+    """Rule search with full paper metrics (compound lift included): CSR
+    bucket descent + consequent-only walk in one scan over the columns."""
     q, width = queries.shape
     e = edge_item.shape[0]
     if e == 0 or width == 0:
         out = _all_not_found(q, "lift")
         out["con_support"] = jnp.zeros((q,), jnp.float32)
         return out
+    ant_len = ant_len.astype(jnp.int32)
+    ecf = edge_conf.astype(jnp.float32)
+    esp = edge_sup.astype(jnp.float32)
+    elf = edge_lift.astype(jnp.float32)
 
-    fan = max(int(max_fanout), 1)
-    n_fan_chunks = -(-fan // block_f)
+    def lookup(nodes, items):
+        j = bucket_edge_lookup(
+            child_offsets, edge_item, max_fanout, nodes, items
+        )
+        jc = jnp.maximum(j, 0)
+        return j >= 0, edge_child[jc], jc
 
-    qp = -q % BQ
-    queries_p = jnp.pad(
-        queries.astype(jnp.int32), ((0, qp), (0, 0)), constant_values=-1
+    def step(carry, col):
+        node, ok, conf, sup, nlift, cnode, cok, csup = carry
+        item, s = col
+        has_item = item >= 0
+        in_cons = s >= ant_len
+
+        active = has_item & ok
+        hit, child, j = lookup(jnp.where(active, node, 0), item)
+        ok = jnp.where(active, hit, ok)
+        adv = active & hit
+        node = jnp.where(adv, child, node)
+        conf = jnp.where(adv & in_cons, conf * ecf[j], conf)
+        sup = jnp.where(adv, esp[j], sup)
+        nlift = jnp.where(adv, elf[j], nlift)
+
+        # root-anchored consequent-only walk (Eq. 1-4 lift denominator)
+        c_active = has_item & in_cons & cok
+        chit, cchild, cj = lookup(jnp.where(c_active, cnode, 0), item)
+        cok = jnp.where(c_active, chit, cok)
+        cadv = c_active & chit
+        cnode = jnp.where(cadv, cchild, cnode)
+        csup = jnp.where(cadv, esp[cj], csup)
+        return (node, ok, conf, sup, nlift, cnode, cok, csup), None
+
+    zi = jnp.zeros((q,), jnp.int32)
+    zf = jnp.zeros((q,), jnp.float32)
+    ones = jnp.ones((q,), jnp.bool_)
+    init = (zi, ones, jnp.ones((q,), jnp.float32), zf, zf, zi, ones, zf)
+    (node, ok, conf, sup, nlift, cnode, cok, csup), _ = jax.lax.scan(
+        step, init, _columns(queries)
     )
-    al_p = jnp.pad(ant_len.astype(jnp.int32), (0, qp)).reshape(-1, 1)
-
-    e_pad = e + (-e % block_f)
-    co_len = child_offsets.shape[0]
-    co_pad = co_len + (-co_len % block_f)
-    co = jnp.pad(
-        child_offsets.astype(jnp.int32), (0, co_pad - co_len),
-        constant_values=e,
-    ).reshape(1, -1)
-
-    def pad_e(a, fill):
-        return jnp.pad(a, (0, e_pad - e), constant_values=fill).reshape(1, -1)
-
-    ei = pad_e(edge_item.astype(jnp.int32), -7)
-    ec = pad_e(edge_child.astype(jnp.int32), -1)
-    ecf = pad_e(edge_conf.astype(jnp.float32), 0.0)
-    esp = pad_e(edge_sup.astype(jnp.float32), 0.0)
-    elf = pad_e(edge_lift.astype(jnp.float32), 0.0)
-
-    qq = queries_p.shape[0]
-    grid = (qq // BQ,)
-    co_spec = pl.BlockSpec((1, co_pad), lambda qi: (0, 0))
-    edge_spec = pl.BlockSpec((1, e_pad), lambda qi: (0, 0))
-    out_specs = [
-        pl.BlockSpec((BQ, 1), lambda qi: (qi, 0)) for _ in range(6)
-    ]
-    out_shapes = [
-        jax.ShapeDtypeStruct((qq, 1), jnp.int32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.int32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.float32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.float32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.float32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.float32),
-    ]
-    node, okv, conf, sup, lift, csup = pl.pallas_call(
-        _make_fused_kernel(width, n_fan_chunks, e_pad, block_f),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BQ, width), lambda qi: (qi, 0)),
-            pl.BlockSpec((BQ, 1), lambda qi: (qi, 0)),
-            co_spec, edge_spec, edge_spec,
-            edge_spec, edge_spec, edge_spec,
-        ],
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(queries_p, al_p, co, ei, ec, ecf, esp, elf)
+    found = ok & (node > 0)
+    seq_len = jnp.sum((queries >= 0).astype(jnp.int32), axis=1)
+    single = (seq_len - ant_len) == 1
+    con_sup = jnp.where(cok & (cnode > 0), csup, 0.0)
     return {
-        "found": okv[:q, 0].astype(bool),
-        "node": node[:q, 0],
-        "confidence": conf[:q, 0],
-        "support": sup[:q, 0],
-        "lift": lift[:q, 0],
+        "found": found,
+        "node": jnp.where(found, node, -1),
+        "confidence": jnp.where(found, conf, 0.0),
+        "support": jnp.where(found, sup, 0.0),
+        "lift": compound_lift(found, single, nlift, conf, con_sup),
         # Support of the consequent-only root walk (0 where that path is
         # absent) — consumed by the sharded cross-device lift merge.
-        "con_support": csup[:q, 0],
+        "con_support": con_sup,
     }
 
 
 # ----------------------------------------------------------------------
-# span kernel: compressed-layout descent + fused consequent walk
+# compressed layout: span-aware descent + consequent walk
 # ----------------------------------------------------------------------
-def _make_span_kernel(width: int, n_fan_chunks: int, e_pad: int,
-                      n_pad: int, block_f: int, n_transactions: int,
-                      confidence_scale: float, lift_scale: float):
-    def kernel(
-        q_ref, al_ref,
-        co_ref, ei_ref, epos_ref, espan_ref, etail_ref,
-        item_ref, sup_ref, conf_ref, lift_ref,
-        pos_ref, ok_ref, conf_out, sup_out, lift_out, csup_ref,
-    ):
-        bq = q_ref.shape[0]
-        qs = q_ref[...]
-        ant_len = al_ref[...][:, 0]
-        co = co_ref[...][0]
-        ei = ei_ref[...][0]
-        epos = epos_ref[...][0]
-        espan = espan_ref[...][0]
-        etail = etail_ref[...][0]
-        icol = item_ref[...][0]
-        # Widen the (possibly quantized) storage columns ONCE: everything
-        # downstream is plain fp32 math shared with the jnp oracle.
-        sup_col, conf_col, lift_col = dequantize_metrics(
-            sup_ref[...][0], conf_ref[...][0], lift_ref[...][0],
-            n_transactions, confidence_scale, lift_scale,
-        )
-
-        def span_step(pos, rem, ctail, items):
-            """One item-consumption step of the compressed descent:
-            span-interior probe (one item-column gather) OR CSR bucket
-            window scan, mirroring ``array_trie.compressed_step``."""
-            in_span = rem > 0
-            nxt = jnp.minimum(pos + 1, n_pad - 1)
-            span_hit = in_span & (icol[nxt] == items)
-            start = co[ctail]
-            count = co[ctail + 1] - start
-            # a bucket holds at most ONE edge per item, so the scan needs
-            # a single masked-max over the flat edge INDEX — the three
-            # span columns then come from cheap [bq] gathers (vs the
-            # plain kernel's four [bq, block_f] metric reduces)
-            best = jnp.full((bq,), -1, jnp.int32)
-            for f in range(n_fan_chunks):
-                offs = (
-                    jax.lax.broadcasted_iota(jnp.int32, (bq, block_f), 1)
-                    + f * block_f
-                )
-                valid = offs < count[:, None]
-                idx = jnp.clip(start[:, None] + offs, 0, e_pad - 1)
-                match = valid & (ei[idx] == items[:, None])
-                best = jnp.maximum(
-                    best, jnp.max(jnp.where(match, idx, -1), axis=1)
-                )
-            safe_best = jnp.maximum(best, 0)
-            sel_pos = epos[safe_best]
-            sel_span = espan[safe_best]
-            sel_tail = etail[safe_best]
-            edge_hit = (~in_span) & (best >= 0)
-            pos2 = jnp.where(
-                span_hit, pos + 1, jnp.where(edge_hit, sel_pos, pos)
-            )
-            rem2 = jnp.where(
-                span_hit, rem - 1, jnp.where(edge_hit, sel_span, rem)
-            )
-            ctail2 = jnp.where(edge_hit, sel_tail, ctail)
-            return pos2, rem2, ctail2, span_hit | edge_hit
-
-        # main walk state (full rule path, positions in DFS space)
-        pos = jnp.zeros((bq,), jnp.int32)
-        rem = jnp.zeros((bq,), jnp.int32)
-        ctail = jnp.zeros((bq,), jnp.int32)
-        ok = jnp.ones((bq,), jnp.bool_)
-        conf = jnp.ones((bq,), jnp.float32)
-        # fused consequent-only walk state (root-anchored, Eq. 1-4 lift)
-        cpos = jnp.zeros((bq,), jnp.int32)
-        crem = jnp.zeros((bq,), jnp.int32)
-        cctail = jnp.zeros((bq,), jnp.int32)
-        cok = jnp.ones((bq,), jnp.bool_)
-
-        for s in range(width):
-            item = qs[:, s]
-            has_item = item >= 0
-            in_cons = s >= ant_len
-
-            active = has_item & ok
-            pos2, rem2, ctail2, hit = span_step(
-                pos, rem, jnp.where(active, ctail, 0), item
-            )
-            ok = jnp.where(active, hit, ok)
-            adv = active & hit
-            conf = jnp.where(adv & in_cons, conf * conf_col[pos2], conf)
-            pos = jnp.where(adv, pos2, pos)
-            rem = jnp.where(adv, rem2, rem)
-            ctail = jnp.where(adv, ctail2, ctail)
-
-            c_active = has_item & in_cons & cok
-            cp2, cr2, ct2, chit = span_step(
-                cpos, crem, jnp.where(c_active, cctail, 0), item
-            )
-            cok = jnp.where(c_active, chit, cok)
-            cadv = c_active & chit
-            cpos = jnp.where(cadv, cp2, cpos)
-            crem = jnp.where(cadv, cr2, crem)
-            cctail = jnp.where(cadv, ct2, cctail)
-
-        found = ok & (pos > 0)
-        seq_len = jnp.sum((qs >= 0).astype(jnp.int32), axis=1)
-        single = (seq_len - ant_len) == 1
-        con_sup = jnp.where(cok & (cpos > 0), sup_col[cpos], 0.0)
-        conf = jnp.where(found, conf, 0.0)
-        pos_ref[...] = jnp.where(found, pos, -1)[:, None]
-        ok_ref[...] = found.astype(jnp.int32)[:, None]
-        conf_out[...] = conf[:, None]
-        sup_out[...] = jnp.where(found, sup_col[pos], 0.0)[:, None]
-        lift_out[...] = compound_lift(
-            found, single, jnp.where(found, lift_col[pos], 0.0),
-            conf, con_sup,
-        )[:, None]
-        csup_ref[...] = con_sup[:, None]
-
-    return kernel
-
-
-def rule_search_span_pallas(
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "max_fanout", "n_transactions", "confidence_scale", "lift_scale",
+    ),
+)
+def rule_search_span(
     child_offsets: jax.Array,  # int32 [Nc+1] compressed CSR buckets
     edge_item: jax.Array,      # int32 [Ec] item-sorted within each bucket
     edge_pos: jax.Array,       # int32 [Ec] child DFS position (run head)
@@ -574,47 +325,16 @@ def rule_search_span_pallas(
     lift: jax.Array,           # f32|bf16|int8 [N]
     queries: jax.Array,        # int32 [Q, L]
     ant_len: jax.Array,        # int32 [Q]
+    *,
     max_fanout: int = 0,       # static: widest compressed bucket
     n_transactions: int = 0,   # static: int32-support denominator
     confidence_scale: float = 1.0,   # static: int8 column scale
     lift_scale: float = 1.0,         # static: int8 column scale
-    interpret: bool = False,
-    block_f: int | None = None,
 ):
-    """Single-launch rule search on the COMPRESSED layout (span-aware
-    descent + fused consequent walk + compound lift).  The ``pos`` output
-    is a DFS position — the ops wrapper maps it to an original node id
-    via ``dfs_to_node``.
-
-    ``block_f`` (bucket-window lanes per fan-out chunk) resolves from the
-    active per-backend ``KernelConfig``'s ``span_bf`` knob when None.
-    """
-    if block_f is None:
-        block_f = get_kernel_config().span_bf
-    return _rule_search_span_impl(
-        child_offsets, edge_item, edge_pos, edge_span, edge_tail,
-        node_item, support, confidence, lift, queries, ant_len,
-        max_fanout=int(max_fanout),
-        n_transactions=int(n_transactions),
-        confidence_scale=float(confidence_scale),
-        lift_scale=float(lift_scale),
-        interpret=interpret, block_f=int(block_f),
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "max_fanout", "n_transactions", "confidence_scale", "lift_scale",
-        "interpret", "block_f",
-    ),
-)
-def _rule_search_span_impl(
-    child_offsets, edge_item, edge_pos, edge_span, edge_tail,
-    node_item, support, confidence, lift, queries, ant_len, *,
-    max_fanout, n_transactions, confidence_scale, lift_scale,
-    interpret, block_f,
-):
+    """Rule search on the COMPRESSED layout (span-aware descent + fused
+    consequent walk + compound lift).  The ``pos`` output is a DFS
+    position — the ops wrapper maps it to an original node id via
+    ``dfs_to_node``."""
     q, width = queries.shape
     e = edge_item.shape[0]
     if e == 0 or width == 0:
@@ -622,85 +342,67 @@ def _rule_search_span_impl(
         out["pos"] = out.pop("node")
         out["con_support"] = jnp.zeros((q,), jnp.float32)
         return out
-
-    fan = max(int(max_fanout), 1)
-    n_fan_chunks = -(-fan // block_f)
-
-    qp = -q % BQ
-    queries_p = jnp.pad(
-        queries.astype(jnp.int32), ((0, qp), (0, 0)), constant_values=-1
+    ant_len = ant_len.astype(jnp.int32)
+    node_item = node_item.astype(jnp.int32)
+    sup_col, conf_col, lift_col = dequantize_metrics(
+        support, confidence, lift,
+        n_transactions, confidence_scale, lift_scale,
     )
-    al_p = jnp.pad(ant_len.astype(jnp.int32), (0, qp)).reshape(-1, 1)
 
-    e_pad = e + (-e % block_f)
-    co_len = child_offsets.shape[0]
-    co_pad = co_len + (-co_len % block_f)
-    co = jnp.pad(
-        child_offsets.astype(jnp.int32), (0, co_pad - co_len),
-        constant_values=e,
-    ).reshape(1, -1)
+    def advance(pos, rem, ctail, items):
+        return span_step(
+            child_offsets, edge_item, edge_pos, edge_span, edge_tail,
+            node_item, max_fanout, pos, rem, ctail, items,
+        )
 
-    def pad_e(a, fill):
-        return jnp.pad(a, (0, e_pad - e), constant_values=fill).reshape(1, -1)
+    def step(carry, col):
+        pos, rem, ctail, ok, conf, cpos, crem, cctail, cok = carry
+        item, s = col
+        has_item = item >= 0
+        in_cons = s >= ant_len
 
-    ei = pad_e(edge_item.astype(jnp.int32), -7)
-    eps = pad_e(edge_pos.astype(jnp.int32), -1)
-    esn = pad_e(edge_span.astype(jnp.int32), 0)
-    etl = pad_e(edge_tail.astype(jnp.int32), 0)
+        active = has_item & ok
+        pos2, rem2, ctail2, hit = advance(
+            pos, rem, jnp.where(active, ctail, 0), item
+        )
+        ok = jnp.where(active, hit, ok)
+        adv = active & hit
+        conf = jnp.where(adv & in_cons, conf * conf_col[pos2], conf)
+        pos = jnp.where(adv, pos2, pos)
+        rem = jnp.where(adv, rem2, rem)
+        ctail = jnp.where(adv, ctail2, ctail)
 
-    n = node_item.shape[0]
-    n_pad = n + (-n % block_f)
+        c_active = has_item & in_cons & cok
+        cp2, cr2, ct2, chit = advance(
+            cpos, crem, jnp.where(c_active, cctail, 0), item
+        )
+        cok = jnp.where(c_active, chit, cok)
+        cadv = c_active & chit
+        cpos = jnp.where(cadv, cp2, cpos)
+        crem = jnp.where(cadv, cr2, crem)
+        cctail = jnp.where(cadv, ct2, cctail)
+        return (pos, rem, ctail, ok, conf, cpos, crem, cctail, cok), None
 
-    def pad_n(a, fill, dtype):
-        return jnp.pad(
-            a.astype(dtype), (0, n_pad - n), constant_values=fill
-        ).reshape(1, -1)
-
-    icol = pad_n(node_item, -7, jnp.int32)
-    spc = pad_n(support, 0, metric_pad_dtype(support))
-    cfc = pad_n(confidence, 0, metric_pad_dtype(confidence))
-    lfc = pad_n(lift, 0, metric_pad_dtype(lift))
-
-    qq = queries_p.shape[0]
-    grid = (qq // BQ,)
-
-    def full_spec(width_):
-        return pl.BlockSpec((1, width_), lambda qi: (0, 0))
-
-    out_specs = [
-        pl.BlockSpec((BQ, 1), lambda qi: (qi, 0)) for _ in range(6)
-    ]
-    out_shapes = [
-        jax.ShapeDtypeStruct((qq, 1), jnp.int32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.int32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.float32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.float32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.float32),
-        jax.ShapeDtypeStruct((qq, 1), jnp.float32),
-    ]
-    pos, okv, conf, sup, lift_o, csup = pl.pallas_call(
-        _make_span_kernel(
-            width, n_fan_chunks, e_pad, n_pad, block_f,
-            n_transactions, confidence_scale, lift_scale,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((BQ, width), lambda qi: (qi, 0)),
-            pl.BlockSpec((BQ, 1), lambda qi: (qi, 0)),
-            full_spec(co_pad), full_spec(e_pad), full_spec(e_pad),
-            full_spec(e_pad), full_spec(e_pad),
-            full_spec(n_pad), full_spec(n_pad), full_spec(n_pad),
-            full_spec(n_pad),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(queries_p, al_p, co, ei, eps, esn, etl, icol, spc, cfc, lfc)
+    zi = jnp.zeros((q,), jnp.int32)
+    ones = jnp.ones((q,), jnp.bool_)
+    init = (zi, zi, zi, ones, jnp.ones((q,), jnp.float32),
+            zi, zi, zi, ones)
+    (pos, _, _, ok, conf, cpos, _, _, cok), _ = jax.lax.scan(
+        step, init, _columns(queries)
+    )
+    found = ok & (pos > 0)
+    seq_len = jnp.sum((queries >= 0).astype(jnp.int32), axis=1)
+    single = (seq_len - ant_len) == 1
+    con_sup = jnp.where(cok & (cpos > 0), sup_col[cpos], 0.0)
+    conf = jnp.where(found, conf, 0.0)
     return {
-        "found": okv[:q, 0].astype(bool),
-        "pos": pos[:q, 0],
-        "confidence": conf[:q, 0],
-        "support": sup[:q, 0],
-        "lift": lift_o[:q, 0],
-        "con_support": csup[:q, 0],
+        "found": found,
+        "pos": jnp.where(found, pos, -1),
+        "confidence": conf,
+        "support": jnp.where(found, sup_col[pos], 0.0),
+        "lift": compound_lift(
+            found, single, jnp.where(found, lift_col[pos], 0.0),
+            conf, con_sup,
+        ),
+        "con_support": con_sup,
     }

@@ -25,7 +25,7 @@ Coordinate plumbing (all precomputed per epoch by the overlay):
   — both exactly what the rebuild would emit;
 * rule search needs no ranking: the frozen kernel answers as-is except
   on rows whose path (or consequent path) touches a modified rule —
-  those recompute host-side in np.float32 mirroring the fused kernel's
+  those recompute host-side in np.float32 mirroring the descent's
   scan-order arithmetic, with the final Eq. 1-4 lift select running
   through the shared jnp ``compound_lift`` (the same outside-the-kernel
   re-select the sharded merge uses, proven bit-identical in its tests).
@@ -369,7 +369,7 @@ def streaming_rule_search_batch(
 
     The frozen kernel answers every row (its descent structure is
     untouched by the overlay); rows touching a modified rule recompute
-    from the union host-side, mirroring the fused kernel's scan-order
+    from the union host-side, mirroring the descent's scan-order
     f32 arithmetic, with the Eq. 1-4 lift select through the shared jnp
     ``compound_lift``.  Node ids remap old -> rebuilt everywhere.
     """

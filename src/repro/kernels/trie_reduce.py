@@ -4,7 +4,8 @@ The paper's headline traversal result (25 min vs >2 h, an ~8× win) is a
 visit-every-rule pass.  On the frozen SoA trie that pass is a masked
 column reduction over the node arrays — this kernel tiles the columns
 through VMEM and accumulates (count, Σ support, max confidence,
-Σ confidence) across grid steps in SMEM-sized output blocks.
+Σ confidence) across grid steps in lanes 0-3 of one ``(1, 128)`` output
+vector (the TPU stores whole vectors to VMEM, never single scalars).
 """
 from __future__ import annotations
 
@@ -18,16 +19,17 @@ from .metrics_inkernel import dequantize_metrics, metric_pad_dtype
 from .tuning import get_kernel_config
 
 BN = 8192   # default nodes per tile (tunable: KernelConfig.reduce_bn)
+LANE = 128  # accumulator width: lanes 0-3 hold the four running values
 
 
 def _make_kernel(n_transactions: int, confidence_scale: float):
     def kernel(sup_ref, conf_ref, depth_ref, out_ref):
         i = pl.program_id(0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
 
         @pl.when(i == 0)
         def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-            out_ref[0, 2] = -jnp.inf
+            out_ref[...] = jnp.where(lane == 2, -jnp.inf, 0.0)
 
         # Quantized columns (compressed layout) widen per tile; lift is
         # unused by this reduction so confidence stands in for it.
@@ -37,12 +39,21 @@ def _make_kernel(n_transactions: int, confidence_scale: float):
         )
         depth = depth_ref[...][0]
         mask = depth > 0
-        out_ref[0, 0] += jnp.sum(mask.astype(jnp.float32))
-        out_ref[0, 1] += jnp.sum(jnp.where(mask, sup, 0.0))
-        out_ref[0, 2] = jnp.maximum(
-            out_ref[0, 2], jnp.max(jnp.where(mask, conf, -jnp.inf))
+        acc = out_ref[...]
+        tile = jnp.where(
+            lane == 0, jnp.sum(mask.astype(jnp.float32)),
+            jnp.where(
+                lane == 1, jnp.sum(jnp.where(mask, sup, 0.0)),
+                jnp.where(
+                    lane == 2, jnp.max(jnp.where(mask, conf, -jnp.inf)),
+                    jnp.sum(jnp.where(mask, conf, 0.0)),
+                ),
+            ),
         )
-        out_ref[0, 3] += jnp.sum(jnp.where(mask, conf, 0.0))
+        out_ref[...] = jnp.where(
+            lane == 2, jnp.maximum(acc, tile),
+            jnp.where(lane < 4, acc + tile, acc),
+        )
 
     return kernel
 
@@ -106,8 +117,8 @@ def _trie_reduce_impl(support, confidence, depth, *, interpret, block_n,
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, 4), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 4), jnp.float32),
+        out_specs=pl.BlockSpec((1, LANE), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, LANE), jnp.float32),
         interpret=interpret,
     )(sup, conf, dep)
     # All-padding tries (no depth > 0 node) never update the running max,

@@ -2,8 +2,8 @@
 
 The Pallas kernels' static launch knobs used to be hard-coded module
 constants picked on one CPU host — ``rank.BN = 8192``,
-``rule_search.BF = 128``, ``item_index.POSTING_WINDOW_EDGES = 512Ki``,
-and the serve scheduler's implicit pow2 launch-pad floor of 1.  The
+``trie_reduce.BN = 8192`` and the serve scheduler's implicit pow2
+launch-pad floor of 1.  The
 data-structure literature is clear that these rankings invert across
 execution environments, so the knobs are now *resolved at op-dispatch
 time* from a committed per-backend tuning table instead:
@@ -23,22 +23,10 @@ count/max outputs stay bitwise, the sums hold to 1e-6).
 Knobs
 -----
 ``rank_bn``
-    Nodes per VMEM tile for the segmented rank / membership kernels
-    (``rank.topk_rank_batch_pallas``, ``item_index.rules_with_pallas``).
+    Nodes per VMEM tile for the segmented rank kernel
+    (``rank.topk_rank_batch_pallas``, which also ranks ``rules_with``).
 ``reduce_bn``
     Nodes per tile for the traversal reduction (``trie_reduce``).
-``search_bf``
-    CSR bucket-window lanes per fan-out chunk in the fused rule-search
-    descent (``rule_search.rule_search_fused_pallas``).
-``span_bf``
-    Same role for the compressed (path-compressed span) layout's
-    descent (``rule_search.rule_search_span_pallas``): bucket-window
-    lanes per chunk of the compressed CSR scan.  Tuned separately
-    because compressed buckets are sparser (span interiors keep no
-    bucket) so the optimal window can differ from ``search_bf``.
-``posting_window_edges``
-    Posting-array edge count above which ``rules_with`` switches from
-    full-array VMEM residency to per-query gathered windows.
 ``launch_pad_floor``
     Minimum row count batched launches pad to (after the next-pow2
     round-up).  1 keeps pure pow2 padding; a larger floor trades a few
@@ -60,13 +48,10 @@ LANE = 128   # TPU lane width: tile knobs must be multiples of this
 class KernelConfig:
     rank_bn: int = 8192
     reduce_bn: int = 8192
-    search_bf: int = 128
-    span_bf: int = 128
-    posting_window_edges: int = 512 * 1024
     launch_pad_floor: int = 1
 
     def validate(self) -> "KernelConfig":
-        for name in ("rank_bn", "reduce_bn", "search_bf", "span_bf"):
+        for name in ("rank_bn", "reduce_bn"):
             v = getattr(self, name)
             if not isinstance(v, int) or v <= 0 or v % LANE:
                 raise ValueError(
@@ -78,14 +63,6 @@ class KernelConfig:
                     f"KernelConfig.{name} must be a power of two "
                     f"(the autotune sweep grid), got {v}"
                 )
-        if (
-            not isinstance(self.posting_window_edges, int)
-            or self.posting_window_edges < 0
-        ):
-            raise ValueError(
-                f"KernelConfig.posting_window_edges must be a "
-                f"non-negative int, got {self.posting_window_edges!r}"
-            )
         f = self.launch_pad_floor
         if not isinstance(f, int) or f < 1 or (f & (f - 1)):
             raise ValueError(

@@ -11,20 +11,13 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto everywhere
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -55,9 +48,6 @@ def make_trie_mesh(n_shards: int | None = None) -> Mesh:
             f"n_shards={n} outside [1, {jax.device_count()}] "
             "visible devices"
         )
-    if AxisType is not None:
-        return Mesh(
-            np.array(jax.devices()[:n]), ("data",),
-            axis_types=(AxisType.Auto,),
-        )
-    return Mesh(np.array(jax.devices()[:n]), ("data",))
+    return Mesh(
+        np.array(jax.devices()[:n]), ("data",), axis_types=(AxisType.Auto,)
+    )

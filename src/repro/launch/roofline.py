@@ -27,14 +27,16 @@ PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 LINK_BW = 50e9
 
-# Per-backend peaks for the KERNEL bench lanes' achieved-vs-peak figure
-# (``kernel_roofline``).  The TPU row is the v5e chip above; the cpu row
-# is a deliberately conservative dual-channel DDR4 envelope (~25.6 GB/s)
-# so interpret-mode utilization figures read as what they are — Python
-# emulation, nowhere near the roof.
+# Peaks for the KERNEL bench lanes' achieved-vs-peak figure
+# (``kernel_roofline``), keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" is how JAX names a TPU v5e chip; its row is Google Cloud's
+# published "TPU v5e" spec (197 TFLOP/s bf16, 819 GB/s HBM), the same
+# constants as above.  The cpu row is a deliberately conservative
+# dual-channel DDR4 envelope (~25.6 GB/s), so interpret-mode figures read
+# as what they are — emulation, nowhere near the roof.  A device kind
+# missing here is an error, never a default.
 KERNEL_PEAKS = {
-    "tpu": {"peak_flops": PEAK_FLOPS, "hbm_gbps": HBM_BW / 1e9},
-    "gpu": {"peak_flops": 989e12, "hbm_gbps": 3350.0},   # H100 SXM bf16
+    "TPU v5 lite": {"peak_flops": PEAK_FLOPS, "hbm_gbps": HBM_BW / 1e9},
     "cpu": {"peak_flops": 1e12, "hbm_gbps": 25.6},
 }
 
@@ -44,26 +46,32 @@ DRYRUN_DIR = os.path.join(
 
 
 def kernel_roofline(
-    bytes_moved: float, seconds: float, backend: Optional[str] = None
+    bytes_moved: float, seconds: float, device_kind: Optional[str] = None
 ) -> Dict:
     """Achieved-vs-peak bandwidth for one kernel bench lane.
 
     ``bytes_moved`` is the lane's streamed working set per call (the
     trie kernels are memory-bound column sweeps, so bytes/peak-BW is the
     relevant roof); ``seconds`` the measured per-call time.  Returns the
-    achieved GB/s, the backend's peak, and their ratio — the
+    achieved GB/s, the device's peak, and their ratio — the
     bandwidth-utilization figure the bench reports emit next to each
-    speedup ratio.  Unknown backends fall back to the cpu envelope.
+    speedup ratio.  ``device_kind`` defaults to the first JAX device's;
+    a kind without a ``KERNEL_PEAKS`` row raises ``ValueError``.
     """
-    if backend is None:
+    if device_kind is None:
         import jax
 
-        backend = jax.default_backend()
-    peaks = KERNEL_PEAKS.get(backend, KERNEL_PEAKS["cpu"])
+        device_kind = jax.devices()[0].device_kind
+    peaks = KERNEL_PEAKS.get(device_kind)
+    if peaks is None:
+        raise ValueError(
+            f"no KERNEL_PEAKS row for device kind {device_kind!r}; add "
+            f"its published peaks with their source"
+        )
     achieved = (bytes_moved / seconds) / 1e9 if seconds > 0 else 0.0
     peak = peaks["hbm_gbps"]
     return {
-        "backend": backend,
+        "device_kind": device_kind,
         "bytes_moved": float(bytes_moved),
         "seconds": float(seconds),
         "achieved_gbps": achieved,

@@ -274,6 +274,7 @@ class ResilientTrieEngine:
             self._replicated = TrieQueryEngine(
                 trie if trie is not None else self.primary.frozen,
                 mode="replicated",
+                layout=getattr(self.primary, "layout", "plain"),
             )
             self._replicated.obs = self._obs
         return self._replicated

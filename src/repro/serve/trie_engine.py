@@ -16,6 +16,10 @@ three batched query ops (``rule_search_batch`` / ``top_k_rules_batch`` /
   outgrows one device's memory or its tile sweep dominates latency —
   each device scans ``~N/P`` nodes per ranked query.
 
+``layout`` picks the frozen encoding both backends hold: ``"plain"``,
+``"compressed"`` (path-compressed spans) or ``"auto"`` (compressed when
+most edges sit on single-child chains — ``FrozenTrie.device_arrays``).
+
 ``mode="auto"`` picks sharded exactly when there is more than one device
 to shard over AND the trie clears ``shard_threshold_nodes`` (default
 64Ki nodes — below that the per-launch tile sweep is a handful of tiles
@@ -59,6 +63,7 @@ class TrieQueryEngine:
         mode: str = "auto",
         shard_threshold_nodes: int = DEFAULT_SHARD_THRESHOLD,
         plan=None,
+        layout: str = "plain",
     ):
         if mode not in ("auto", "replicated", "sharded"):
             raise ValueError(
@@ -68,6 +73,7 @@ class TrieQueryEngine:
         # runs under an ``engine.<op>`` span on the shared tracer
         self.obs = None
         self.stream = None
+        self.layout = layout
         if isinstance(frozen, StreamingTrie):
             self.stream = frozen
             frozen = None
@@ -119,7 +125,7 @@ class TrieQueryEngine:
                 mesh = make_trie_mesh()
             from repro.distributed.trie_sharding import shard_device_trie
 
-            self._plan = shard_device_trie(frozen, mesh)
+            self._plan = shard_device_trie(frozen, mesh, layout=layout)
         self.mesh = mesh
 
     # ------------------------------------------------------------------
@@ -156,7 +162,7 @@ class TrieQueryEngine:
 
     def _device_trie(self):
         if self._dt is None:
-            self._dt = self.frozen.device_arrays()
+            self._dt = self.frozen.device_arrays(layout=self.layout)
         return self._dt
 
     def _span(self, name: str, **attrs):
@@ -315,10 +321,11 @@ def make_trie_engine(
     mesh=None,
     mode: str = "auto",
     shard_threshold_nodes: int = DEFAULT_SHARD_THRESHOLD,
+    layout: str = "plain",
 ) -> TrieQueryEngine:
     """Factory alias (mirrors the ``make_*_step`` serving constructors).
     ``frozen`` may be a ``FrozenTrie`` or a ``StreamingTrie``."""
     return TrieQueryEngine(
         frozen, mesh=mesh, mode=mode,
-        shard_threshold_nodes=shard_threshold_nodes,
+        shard_threshold_nodes=shard_threshold_nodes, layout=layout,
     )

@@ -203,7 +203,7 @@ def test_topk_rank_batch_kernel_oracle_parity(k):
 
 @pytest.mark.slow
 def test_rules_with_pallas_matches_ref_on_synthetic():
-    """Raw membership kernel vs the searchsorted reference on an
+    """Raw membership ranking vs the searchsorted reference on an
     irregular synthetic trie, every role, with ties (deep tie coverage:
     the fast job's role×metric sweep runs on the mined trie instead)."""
     arrs = synthetic_csr_trie(5_000, seed=9)
@@ -238,28 +238,22 @@ def test_rules_with_pallas_matches_ref_on_synthetic():
     items_j = jnp.asarray(items, jnp.int32)
     for role in ROLES:
         for k in (10, 100):
-            # both posting layouts (full-array residency AND the
-            # max_postings-bounded per-query windows) against the ref
-            for window in (False, True):
-                kv, kp = rules_with_pallas(
-                    sup, conf, lif, dep, nit, post_lo, post_hi,
-                    plos, phis, items_j,
-                    k=k, metric="support", role=role,
-                    max_postings=arrs["max_postings"], window=window,
-                    interpret=True,
-                )
-                rv, rp = rules_with_ref(
-                    sup, conf, lif, dep, nit, post_lo, post_hi,
-                    plos, phis, items_j, k=k, metric="support", role=role,
-                )
-                np.testing.assert_array_equal(
-                    np.asarray(kv), np.asarray(rv),
-                    err_msg=f"{role} k={k} window={window}",
-                )
-                np.testing.assert_array_equal(
-                    np.asarray(kp), np.asarray(rp),
-                    err_msg=f"{role} k={k} window={window}",
-                )
+            kv, kp = rules_with_pallas(
+                sup, conf, lif, dep, nit, post_lo, post_hi,
+                plos, phis, items_j,
+                k=k, metric="support", role=role,
+                max_postings=arrs["max_postings"], interpret=True,
+            )
+            rv, rp = rules_with_ref(
+                sup, conf, lif, dep, nit, post_lo, post_hi,
+                plos, phis, items_j, k=k, metric="support", role=role,
+            )
+            np.testing.assert_array_equal(
+                np.asarray(kv), np.asarray(rv), err_msg=f"{role} k={k}",
+            )
+            np.testing.assert_array_equal(
+                np.asarray(kp), np.asarray(rp), err_msg=f"{role} k={k}",
+            )
 
 
 # ----------------------------------------------------------------------

@@ -43,7 +43,7 @@ from repro.core.synthetic import (
 from repro.core.trie import TrieOfRules
 from repro.kernels import ops
 from repro.kernels.ref import rule_search_span_ref
-from repro.kernels.rule_search import rule_search_span_pallas
+from repro.kernels.rule_search import rule_search_span
 
 METRICS = ("confidence", "lift", "support", "conviction")
 ROLES = ("any", "antecedent", "consequent")
@@ -226,11 +226,11 @@ class TestBitParity:
         arrs = chain_trie(900, chain_fraction=0.8)
         dtc = device_trie_from_arrays(arrs, layout="compressed")
         q, al = _queries(arrs, seed=4)
-        out = rule_search_span_pallas(
+        out = rule_search_span(
             dtc.child_offsets, dtc.edge_item, dtc.edge_child,
             dtc.edge_span, dtc.edge_tail, dtc.node_item,
             dtc.support, dtc.confidence, dtc.lift, q, al,
-            max_fanout=dtc.max_fanout, interpret=True,
+            max_fanout=dtc.max_fanout,
         )
         ref = rule_search_span_ref(
             dtc.edge_parent, dtc.edge_item, dtc.edge_child,
@@ -432,11 +432,11 @@ class TestQuantized:
             confidence_scale=dtq.confidence_scale,
             lift_scale=dtq.lift_scale,
         )
-        out = rule_search_span_pallas(
+        out = rule_search_span(
             dtq.child_offsets, dtq.edge_item, dtq.edge_child,
             dtq.edge_span, dtq.edge_tail, dtq.node_item,
             dtq.support, dtq.confidence, dtq.lift, q, al,
-            max_fanout=dtq.max_fanout, interpret=True, **dq,
+            max_fanout=dtq.max_fanout, **dq,
         )
         ref = rule_search_span_ref(
             dtq.edge_parent, dtq.edge_item, dtq.edge_child,

@@ -24,7 +24,7 @@ from repro.core.array_trie import (
 from repro.kernels.ops import edge_metric_arrays, rule_search
 from repro.kernels.ref import rule_search_fused_ref
 from repro.kernels.rule_search import (
-    rule_search_fused_pallas,
+    rule_search_fused,
     rule_search_pallas,
 )
 
@@ -112,7 +112,7 @@ def test_oracle_csr_matches_seed_search(n_nodes, n_items, q, width,
 
 
 # ----------------------------------------------------------------------
-# fused kernel ≡ jnp oracle ≡ full-table reference
+# bucket descent ≡ jnp oracle ≡ full-table reference
 # ----------------------------------------------------------------------
 @pytest.mark.parametrize(
     "n_nodes,n_items,q,width",
@@ -132,9 +132,9 @@ def test_fused_kernel_parity(n_nodes, n_items, q, width, random_trie,
         jnp.asarray(arrs[col])[jnp.asarray(arrs["edge_child"])]
         for col in ("confidence", "support", "lift")
     ]
-    out = rule_search_fused_pallas(
+    out = rule_search_fused(
         jnp.asarray(arrs["child_offsets"]), *edge_args, *emetrics,
-        qj, alj, max_fanout=arrs["max_fanout"], interpret=True,
+        qj, alj, max_fanout=arrs["max_fanout"],
     )
     ref = rule_search_fused_ref(
         jnp.asarray(arrs["edge_parent"]), *edge_args, *emetrics, qj, alj
@@ -162,10 +162,10 @@ def test_fused_kernel_parity(n_nodes, n_items, q, width, random_trie,
 
 
 def test_fused_kernel_hub_bucket_chunked_sweep(query_mix):
-    """Root fanout > BF=128 forces n_fan_chunks > 1 — the chunked sweep
-    over a hub node's bucket window must stay bit-identical to the
-    reference (the low-minsup production shape: many frequent 1-items)."""
-    root_fanout = 300  # > 2*BF: three fan chunks
+    """A hub root (fanout 300) — the bucket binary search over a wide
+    bucket must stay bit-identical to the full-table reference (the
+    low-minsup production shape: many frequent 1-items)."""
+    root_fanout = 300
     parent = [-1]
     item = [-1]
     edges = []
@@ -186,7 +186,7 @@ def test_fused_kernel_hub_bucket_chunked_sweep(query_mix):
     n_nodes = len(parent)
     rng = np.random.RandomState(7)
     offsets, max_fanout = csr_offsets_from_edges(e[:, 0], n_nodes)
-    assert max_fanout == root_fanout  # hub confirmed wider than 2 tiles
+    assert max_fanout == root_fanout  # the hub is the widest bucket
     arrs = {
         "node_parent": np.asarray(parent, np.int32),
         "node_item": np.asarray(item, np.int32),
@@ -206,10 +206,10 @@ def test_fused_kernel_hub_bucket_chunked_sweep(query_mix):
         jnp.asarray(arrs[col])[jnp.asarray(arrs["edge_child"])]
         for col in ("confidence", "support", "lift")
     ]
-    out = rule_search_fused_pallas(
+    out = rule_search_fused(
         jnp.asarray(offsets), jnp.asarray(arrs["edge_item"]),
         jnp.asarray(arrs["edge_child"]), *emetrics,
-        qj, alj, max_fanout=max_fanout, interpret=True,
+        qj, alj, max_fanout=max_fanout,
     )
     ref = rule_search_fused_ref(
         jnp.asarray(arrs["edge_parent"]), jnp.asarray(arrs["edge_item"]),
@@ -291,10 +291,9 @@ def test_empty_edge_table_kernels_guarded():
         queries, al, interpret=True,
     )
     assert not bool(out["found"][0]) and int(out["node"][0]) == -1
-    out = rule_search_fused_pallas(
+    out = rule_search_fused(
         jnp.asarray([0, 0], jnp.int32), empty_i, empty_i,
-        empty_f, empty_f, empty_f, queries, al,
-        max_fanout=0, interpret=True,
+        empty_f, empty_f, empty_f, queries, al, max_fanout=0,
     )
     assert not bool(out["found"][0]) and int(out["node"][0]) == -1
     assert float(out["lift"][0]) == 0.0

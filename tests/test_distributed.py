@@ -13,21 +13,10 @@ from repro.models import materialize_params
 from repro.models.moe import moe_alltoall, moe_dense
 
 
-needs_set_mesh = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="jax.set_mesh not available in this jax version",
-)
-
-
 def _mesh(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.sharding.AbstractMesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    # older jax: AbstractMesh takes ((name, size), ...) and has no
-    # axis-type concept (everything is implicitly Auto)
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 class TestLogicalRules:
@@ -87,7 +76,6 @@ class TestLogicalRules:
         assert spec == P("model")
 
 
-@needs_set_mesh
 class TestMoEParity:
     def test_dense_equals_alltoall_on_host_mesh(self):
         """The EP path (sort/capacity/psum) must reproduce the dense
@@ -150,7 +138,6 @@ class TestElastic:
         assert out["w"].sharding.mesh.shape == dict(mesh.shape)
 
 
-@needs_set_mesh
 class TestHostMeshLowering:
     """specs + jit plumbing compiles on the local 1-device mesh."""
 

@@ -19,7 +19,7 @@ from repro.kernels.ref import (
 )
 from repro.kernels.support_count import support_count_pallas
 from repro.kernels.rule_search import (
-    rule_search_fused_pallas,
+    rule_search_fused,
     rule_search_pallas,
 )
 from repro.kernels.trie_reduce import trie_reduce_pallas
@@ -154,8 +154,8 @@ def test_rule_search_sweep(n_nodes, n_items, q, width, random_trie):
     [(5, 4, 3, 2), (50, 12, 40, 5), (200, 30, 129, 7), (512, 64, 256, 4)],
 )
 def test_rule_search_fused_sweep(n_nodes, n_items, q, width, random_trie):
-    """Fused CSR kernel ≡ layout-agnostic full-table reference (incl. the
-    compound lift it computes in-kernel)."""
+    """CSR bucket descent ≡ layout-agnostic full-table reference (incl.
+    the compound lift it assembles)."""
     rng = np.random.RandomState(n_nodes + q)
     arrs = random_trie(rng, n_nodes, n_items, max_children=4)
     queries = rng.randint(-1, n_items, size=(q, width)).astype(np.int32)
@@ -171,10 +171,10 @@ def test_rule_search_fused_sweep(n_nodes, n_items, q, width, random_trie):
             "edge_conf", "edge_sup", "edge_lift",
         )
     ]
-    out = rule_search_fused_pallas(
+    out = rule_search_fused(
         jnp.asarray(offsets), *args,
         jnp.asarray(queries), jnp.asarray(ant_len),
-        max_fanout=max_fanout, interpret=True,
+        max_fanout=max_fanout,
     )
     ref = rule_search_fused_ref(
         jnp.asarray(arrs["edge_parent"]), *args,

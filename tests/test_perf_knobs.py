@@ -67,10 +67,6 @@ def test_remat_policy_dots_same_loss():
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="jax.set_mesh not available in this jax version",
-)
 def test_moe_psum_bf16_close():
     """bf16 psum knob changes only low-order bits of the MoE output."""
     from dataclasses import replace

@@ -1,4 +1,4 @@
-"""Roofline analysis: the per-backend kernel bandwidth figure
+"""Roofline analysis: the per-device-kind kernel bandwidth figure
 (``kernel_roofline``), the model-side term math on a synthetic dry-run
 record, and the perf hillclimb driver's variant table."""
 import math
@@ -11,31 +11,34 @@ from repro.launch import roofline
 class TestKernelRoofline:
     def test_achieved_and_util_math(self):
         # 25.6 GB moved in 2 s = 12.8 GB/s achieved = half the cpu peak
-        out = roofline.kernel_roofline(25.6e9, 2.0, backend="cpu")
-        assert out["backend"] == "cpu"
+        out = roofline.kernel_roofline(25.6e9, 2.0, device_kind="cpu")
+        assert out["device_kind"] == "cpu"
         assert out["achieved_gbps"] == pytest.approx(12.8)
         assert out["peak_gbps"] == pytest.approx(25.6)
         assert out["bandwidth_util"] == pytest.approx(0.5)
 
     def test_zero_seconds_is_zero_not_inf(self):
-        out = roofline.kernel_roofline(1e9, 0.0, backend="cpu")
+        out = roofline.kernel_roofline(1e9, 0.0, device_kind="cpu")
         assert out["achieved_gbps"] == 0.0
         assert out["bandwidth_util"] == 0.0
 
-    def test_unknown_backend_falls_back_to_cpu_envelope(self):
-        out = roofline.kernel_roofline(1e9, 1.0, backend="quantum")
-        assert out["peak_gbps"] == roofline.KERNEL_PEAKS["cpu"]["hbm_gbps"]
+    def test_unknown_device_kind_raises(self):
+        with pytest.raises(ValueError, match="quantum"):
+            roofline.kernel_roofline(1e9, 1.0, device_kind="quantum")
 
     def test_default_backend_resolves(self):
+        import jax
+
         out = roofline.kernel_roofline(1e9, 1.0)
-        assert out["backend"] in {"cpu", "gpu", "tpu"}
+        assert out["device_kind"] == jax.devices()[0].device_kind
+        assert out["device_kind"] in roofline.KERNEL_PEAKS
 
     def test_peaks_table_shape(self):
         for name, peaks in roofline.KERNEL_PEAKS.items():
             assert peaks["peak_flops"] > 0, name
             assert peaks["hbm_gbps"] > 0, name
-        # the tpu row must stay consistent with the model-side constants
-        tpu = roofline.KERNEL_PEAKS["tpu"]
+        # the v5e row must stay consistent with the model-side constants
+        tpu = roofline.KERNEL_PEAKS["TPU v5 lite"]
         assert tpu["peak_flops"] == roofline.PEAK_FLOPS
         assert tpu["hbm_gbps"] == pytest.approx(roofline.HBM_BW / 1e9)
 

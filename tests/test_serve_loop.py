@@ -229,6 +229,40 @@ def test_error_taxonomy_classification():
     assert not is_retryable(RuntimeError("segfault"))
 
 
+# what the TPU compiler raises for a kernel over its VMEM budget (the
+# first, verbatim from a v5e compile of an oversized rank tile) and for a
+# program over the device's memory: RESOURCE_EXHAUSTED, but permanent
+COMPILE_REFUSALS = (
+    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+    "memory in memory space vmem. Used 175.87M of 128.00M vmem. "
+    "Exceeded vmem capacity by 47.87M.",
+    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+    "memory in memory space hbm. Used 20.5G of 15.75G hbm.",
+)
+
+
+@pytest.mark.parametrize("msg", COMPILE_REFUSALS)
+def test_compile_refusal_surfaces_at_once(fz, msg):
+    assert not is_retryable(RuntimeError(msg))
+    eng = TrieQueryEngine(fz, mode="replicated")
+    calls = []
+
+    def refuse(*args, **kwargs):
+        calls.append(1)
+        raise RuntimeError(msg)
+
+    eng.rules_with = refuse
+    sched = TrieScheduler(
+        eng, clock=VirtualClock(),
+        retry_policy=RetryPolicy(max_retries=3, base_ms=1.0),
+    )
+    r = sched.submit("rules_with", 1, {"k": 4})
+    sched.drain()
+    resp = sched.responses[r.id]
+    assert resp.status == "failed" and resp.retries == 0
+    assert len(calls) == 1
+
+
 # ----------------------------------------------------------------------
 # poison-query isolation
 # ----------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """The kernel-tuning registry: KernelConfig validation, table
 resolution order (override > committed table > defaults), the scoped
 ``tuning_overrides`` context, ``launch_pad``'s floor semantics, and the
-``REPRO_FORCE_INTERPRET`` execution-mode override."""
+``REPRO_FORCE_INTERPRET`` execution-mode override (refused on a TPU)."""
 import dataclasses
 import json
 
@@ -39,12 +39,10 @@ class TestKernelConfig:
     def test_defaults_are_historical_constants(self):
         assert DEFAULTS.rank_bn == 8192
         assert DEFAULTS.reduce_bn == 8192
-        assert DEFAULTS.search_bf == 128
-        assert DEFAULTS.posting_window_edges == 512 * 1024
         assert DEFAULTS.launch_pad_floor == 1
         DEFAULTS.validate()  # defaults must self-validate
 
-    @pytest.mark.parametrize("knob", ["rank_bn", "reduce_bn", "search_bf"])
+    @pytest.mark.parametrize("knob", ["rank_bn", "reduce_bn"])
     @pytest.mark.parametrize("bad", [0, -128, 100, 192, 8192 + 128])
     def test_tile_knobs_must_be_pow2_lane_multiples(self, knob, bad):
         with pytest.raises(ValueError):
@@ -54,12 +52,6 @@ class TestKernelConfig:
     def test_launch_pad_floor_must_be_pow2(self, bad):
         with pytest.raises(ValueError):
             dataclasses.replace(DEFAULTS, launch_pad_floor=bad).validate()
-
-    def test_negative_posting_window_rejected(self):
-        with pytest.raises(ValueError):
-            dataclasses.replace(
-                DEFAULTS, posting_window_edges=-1
-            ).validate()
 
 
 class TestTableResolution:
@@ -105,9 +97,9 @@ class TestTableResolution:
 class TestTuningOverrides:
     def test_scoped_override_and_restore(self, isolated_tables):
         before = get_kernel_config()
-        with tuning_overrides(search_bf=256) as cfg:
-            assert cfg.search_bf == 256
-            assert get_kernel_config().search_bf == 256
+        with tuning_overrides(reduce_bn=4096) as cfg:
+            assert cfg.reduce_bn == 4096
+            assert get_kernel_config().reduce_bn == 4096
         assert get_kernel_config() == before
 
     def test_unknown_knob_rejected(self, isolated_tables):
@@ -117,11 +109,11 @@ class TestTuningOverrides:
 
     def test_nested_overrides_compose(self, isolated_tables):
         with tuning_overrides(rank_bn=4096):
-            with tuning_overrides(search_bf=256) as inner:
+            with tuning_overrides(reduce_bn=2048) as inner:
                 # inner layers on top of the outer override
                 assert inner.rank_bn == 4096
-                assert inner.search_bf == 256
-            assert get_kernel_config().search_bf == DEFAULTS.search_bf
+                assert inner.reduce_bn == 2048
+            assert get_kernel_config().reduce_bn == DEFAULTS.reduce_bn
             assert get_kernel_config().rank_bn == 4096
 
     def test_knob_names_cover_all_fields(self):
@@ -178,3 +170,15 @@ class TestInterpretMode:
 
     def test_back_compat_alias(self):
         assert ops._interpret is ops.interpret_mode
+
+    @pytest.mark.parametrize("val", ["1", "interpret"])
+    def test_truthy_refused_on_tpu(self, monkeypatch, val):
+        monkeypatch.setenv("REPRO_FORCE_INTERPRET", val)
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="on a TPU"):
+            ops.interpret_mode()
+
+    def test_tpu_compiles_by_default(self, monkeypatch):
+        monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+        assert ops.interpret_mode() is False
