@@ -89,48 +89,20 @@ _interpret = interpret_mode
 
 
 # ----------------------------------------------------------------------
-# kernel-launch profiling (repro.obs: one timing ring per op)
+# profiler annotations: ``ops.<op>.<phase>``
 # ----------------------------------------------------------------------
-from time import perf_counter as _perf_counter  # noqa: E402
-
-from repro.obs.profile import kernel_profiler as _kernel_profiler  # noqa: E402
+from repro.obs.trace import scope as _scope  # noqa: E402
 
 
-def _live_query_rows(queries) -> Optional[int]:
-    """Rows carrying any real (non-negative) item, or None when the
-    profiler is off — launch pad rows are all-padding / absent-item
-    rows by the repo-wide query-matrix conventions, so this is the
-    denominator of the recorded pad factor."""
-    if not _kernel_profiler.enabled:
-        return None
-    q = np.asarray(queries)
-    if q.ndim != 2:
-        return int(q.shape[0]) if q.ndim == 1 else None
-    return int(np.count_nonzero((q >= 0).any(axis=1)))
-
-
-def _profiled(op, fn, *, rows, shape, live=None, n_shards=1):
-    """Run one kernel dispatch under the launch profiler.
-
-    Disabled (the default): one attribute read, then ``fn()`` untouched
-    — results, dispatch, and async behavior are bit-identical to the
-    uninstrumented call.  Enabled: the result is blocked on before the
-    clock stops (honest wall time under async dispatch) and the record
-    lands in the per-op ring (``repro.obs.profile.kernel_profiler``),
-    fanning out to the bound registry and any observers."""
-    if not _kernel_profiler.enabled:
-        return fn()
-    t0 = _perf_counter()
-    out = jax.block_until_ready(fn())
-    rows = max(int(rows), 1)
-    live_rows = rows if live is None else min(max(int(live), 1), rows)
-    _kernel_profiler.record(
-        op, rows=rows, shape=tuple(int(s) for s in shape),
-        seconds=_perf_counter() - t0,
-        pad_factor=rows / live_rows,
-        n_shards=int(n_shards),
-    )
-    return out
+def _phase(op: str, phase: str, **attrs):
+    """The ``ops.<op>.<phase>`` annotation (``repro.obs.trace.scope``)
+    around one phase of an op, so a ``jax.profiler`` trace splits its
+    host time.  Phases are siblings, never nested: ``prep`` (host numpy
+    work and uploads before the launch), ``prefix_ranges`` (``top_k``'s
+    per-column eager descent), ``kernel`` (the dispatch of the jitted or
+    Pallas program; ``rows`` its launch rows) and ``expand`` (the eager
+    gathers after it, back to query rows and node ids)."""
+    return _scope(f"ops.{op}.{phase}", **attrs)
 
 
 # ----------------------------------------------------------------------
@@ -599,10 +571,11 @@ def rule_search(
         from .streaming import streaming_rule_search_batch
 
         return streaming_rule_search_batch(stream, queries, ant_len)
-    if edges is None:
-        edges = edge_metric_arrays(trie)
-    queries = jnp.asarray(queries, jnp.int32)
-    ant_len = jnp.asarray(ant_len, jnp.int32)
+    with _phase("rule_search", "prep"):
+        if edges is None:
+            edges = edge_metric_arrays(trie)
+        queries = jnp.asarray(queries, jnp.int32)
+        ant_len = jnp.asarray(ant_len, jnp.int32)
 
     if queries.shape[0] == 0:
         # Q == 0: nothing to search; avoid tracing a zero-grid kernel.
@@ -613,11 +586,10 @@ def rule_search(
             "support": z, "confidence": z, "lift": z,
         }
 
-    live = _live_query_rows(queries)
+    rows = queries.shape[0]
     if edges.get("layout") == "compressed":
-        out = _profiled(
-            "rule_search",
-            lambda: rule_search_span(
+        with _phase("rule_search", "kernel", rows=rows):
+            out = rule_search_span(
                 edges["child_offsets"], edges["edge_item"],
                 edges["edge_pos"], edges["edge_span"], edges["edge_tail"],
                 edges["node_item"], edges["support"], edges["confidence"],
@@ -626,12 +598,12 @@ def rule_search(
                 n_transactions=edges["n_transactions"],
                 confidence_scale=edges["confidence_scale"],
                 lift_scale=edges["lift_scale"],
-            ),
-            rows=queries.shape[0], shape=queries.shape, live=live,
-        )
+            )
         # The span descent reports DFS positions; the op-level contract is
         # original node ids (same dict shape as the plain paths).
-        node = _pos_to_node(out["found"], out["pos"], edges["dfs_to_node"])
+        with _phase("rule_search", "expand"):
+            node = _pos_to_node(out["found"], out["pos"],
+                                edges["dfs_to_node"])
         return {
             "found": out["found"],
             "node": node,
@@ -641,55 +613,49 @@ def rule_search(
         }
 
     if edges.get("child_offsets") is not None:
-        out = _profiled(
-            "rule_search",
-            lambda: rule_search_fused(
+        with _phase("rule_search", "kernel", rows=rows):
+            out = rule_search_fused(
                 edges["child_offsets"], edges["edge_item"],
                 edges["edge_child"], edges["edge_conf"], edges["edge_sup"],
                 edges["edge_lift"], queries, ant_len,
                 max_fanout=edges["max_fanout"],
-            ),
-            rows=queries.shape[0], shape=queries.shape, live=live,
-        )
+            )
         # con_support is descent plumbing for the sharded merge, not part
         # of the op-level contract (keeps single/sharded dicts identical)
         return {k: v for k, v in out.items() if k != "con_support"}
 
     interp = _interpret()
-    full = _profiled(
-        "rule_search",
-        lambda: rule_search_pallas(
+    with _phase("rule_search", "kernel", rows=rows):
+        full = rule_search_pallas(
             edges["edge_parent"], edges["edge_item"], edges["edge_child"],
             edges["edge_conf"], edges["edge_sup"], edges["edge_lift"],
             queries, ant_len, interpret=interp,
-        ),
-        rows=queries.shape[0], shape=queries.shape, live=live,
-    )
+        )
     # Consequent-only walk for compound lift (Eq. 1-4): keep consequent
     # columns, blank the antecedent, walk from the root.
-    width = queries.shape[1]
-    cols = jnp.arange(width, dtype=jnp.int32)[None, :]
-    cons_q = jnp.where(cols >= ant_len[:, None], queries, -1)
-    cons = _profiled(
-        "rule_search",
-        lambda: rule_search_pallas(
+    with _phase("rule_search", "prep"):
+        width = queries.shape[1]
+        cols = jnp.arange(width, dtype=jnp.int32)[None, :]
+        cons_q = jnp.where(cols >= ant_len[:, None], queries, -1)
+    with _phase("rule_search", "kernel", rows=rows):
+        cons = rule_search_pallas(
             edges["edge_parent"], edges["edge_item"], edges["edge_child"],
             edges["edge_conf"], edges["edge_sup"], edges["edge_lift"],
             cons_q, jnp.zeros_like(ant_len), interpret=interp,
-        ),
-        rows=queries.shape[0], shape=queries.shape, live=live,
-    )
-    seq_len = jnp.sum(queries >= 0, axis=1).astype(jnp.int32)
-    single = (seq_len - ant_len) == 1
+        )
+    with _phase("rule_search", "expand"):
+        seq_len = jnp.sum(queries >= 0, axis=1).astype(jnp.int32)
+        single = (seq_len - ant_len) == 1
+        lift = compound_lift(
+            full["found"], single, full["node_lift"],
+            full["confidence"], cons["support"],
+        )
     return {
         "found": full["found"],
         "node": full["node"],
         "support": full["support"],
         "confidence": full["confidence"],
-        "lift": compound_lift(
-            full["found"], single, full["node_lift"],
-            full["confidence"], cons["support"],
-        ),
+        "lift": lift,
     }
 
 
@@ -807,19 +773,17 @@ def top_k_rules(
         functools.partial(topk_rank_pallas, interpret=_interpret())
         if use_kernel else topk_rank_ref
     )
-    vals, pos = _profiled(
-        "top_k",
-        lambda: rank_fn(
+    with _phase("top_k", "kernel", rows=1):
+        vals, pos = rank_fn(
             arrays["support"], arrays["confidence"], arrays["lift"],
             arrays["depth"], lo, hi,
             k=int(k), metric=metric, min_depth=int(min_depth),
             **_dequant_statics(arrays),
-        ),
-        rows=1, shape=(int(n), int(k)),
-    )
-    node_ids = jnp.where(
-        pos >= 0, arrays["dfs_to_node"][jnp.maximum(pos, 0)], -1
-    )
+        )
+    with _phase("top_k", "expand"):
+        node_ids = jnp.where(
+            pos >= 0, arrays["dfs_to_node"][jnp.maximum(pos, 0)], -1
+        )
     return {"values": vals, "node": node_ids, "dfs_pos": pos}
 
 
@@ -1015,67 +979,60 @@ def rules_with(
                 "already owns its device residency) nor use_kernel=False "
                 "(the jnp oracle is single-device only)"
             )
-        items = validate_items(
-            items, "rules_with",
-            n_items=plan.local_item_offsets.shape[1] - 1, strict=strict,
-        )
+        with _phase("rules_with", "prep"):
+            items = validate_items(
+                items, "rules_with",
+                n_items=plan.local_item_offsets.shape[1] - 1, strict=strict,
+            )
         from repro.distributed.trie_sharding import sharded_rules_with
 
-        return _profiled(
-            "rules_with",
-            lambda: sharded_rules_with(
+        with _phase("rules_with", "kernel", rows=len(items)):
+            return sharded_rules_with(
                 plan, items, role=role, k=k, metric=metric,
                 min_depth=min_depth,
-            ),
-            rows=len(items), shape=(len(items), int(k)),
-            n_shards=plan.n_shards,
+            )
+    with _phase("rules_with", "prep"):
+        if arrays is None:
+            arrays = item_rank_arrays(trie)
+        items = validate_items(
+            items, "rules_with",
+            n_items=arrays["item_offsets"].shape[0] - 1, strict=strict,
         )
-    if arrays is None:
-        arrays = item_rank_arrays(trie)
-    items = validate_items(
-        items, "rules_with",
-        n_items=arrays["item_offsets"].shape[0] - 1, strict=strict,
-    )
-    plos, phis, qitems = _posting_slices(arrays["item_offsets"], items)
-    # Duplicate-item dedup: identical (sanitized) items produce
-    # bit-identical result rows, and the membership mask gathers a
-    # [Q, ~max_postings] posting window per query — running the launch
-    # over the U unique items bounds that at [U, ...] and cuts compute on
-    # skewed traffic; rows expand back via the inverse map afterwards.
-    # (Every absent item sanitizes to -1, so they dedup together too.)
-    # U pads up to a power of two so a serving stream of fixed-Q batches
-    # with varying duplicate multiplicity hits a bounded set of compiled
-    # launch shapes instead of one trace per distinct unique-count; the
-    # pad rows are absent-item queries (empty slice, item -1) that no
-    # inverse-map entry ever reads.
-    _, first, inv = np.unique(
-        qitems, return_index=True, return_inverse=True
-    )
-    plos, phis, qitems = _pad_pow2_rows(
-        plos[first], phis[first], qitems[first]
-    )
-    plos_j = jnp.asarray(plos)
-    phis_j = jnp.asarray(phis)
-    live = (
-        int(np.count_nonzero(np.asarray(qitems) >= 0))
-        if _kernel_profiler.enabled else None
-    )
+        plos, phis, qitems = _posting_slices(arrays["item_offsets"], items)
+        # Duplicate-item dedup: identical (sanitized) items produce
+        # bit-identical result rows, and the membership mask gathers a
+        # [Q, ~max_postings] posting window per query — running the
+        # launch over the U unique items bounds that at [U, ...] and cuts
+        # compute on skewed traffic; rows expand back via the inverse map
+        # afterwards.  (Every absent item sanitizes to -1, so they dedup
+        # together too.)  U pads up to a power of two so a serving stream
+        # of fixed-Q batches with varying duplicate multiplicity hits a
+        # bounded set of compiled launch shapes instead of one trace per
+        # distinct unique-count; the pad rows are absent-item queries
+        # (empty slice, item -1) that no inverse-map entry ever reads.
+        _, first, inv = np.unique(
+            qitems, return_index=True, return_inverse=True
+        )
+        plos, phis, qitems = _pad_pow2_rows(
+            plos[first], phis[first], qitems[first]
+        )
+        plos_j = jnp.asarray(plos)
+        phis_j = jnp.asarray(phis)
+        qitems_j = jnp.asarray(qitems)
+        inv_j = jnp.asarray(inv, jnp.int32)
+    rows = plos.shape[0]
     if role == "consequent" and "p_support" in arrays:
         rank_fn = (
             functools.partial(topk_rank_batch_pallas, interpret=_interpret())
             if use_kernel else topk_rank_batch_ref
         )
-        vals, pos = _profiled(
-            "rules_with",
-            lambda: rank_fn(
+        with _phase("rules_with", "kernel", rows=rows):
+            vals, pos = rank_fn(
                 arrays["p_support"], arrays["p_confidence"],
                 arrays["p_lift"], arrays["p_depth"],
                 plos_j, phis_j,
                 k=int(k), metric=metric, min_depth=int(min_depth),
-            ),
-            rows=plos.shape[0], shape=(int(plos.shape[0]), int(k)),
-            live=live,
-        )
+            )
         back = arrays["item_nodes"]
     else:
         # The compressed layout has no posting-ordered column block, so
@@ -1087,30 +1044,26 @@ def rules_with(
             functools.partial(rules_with_pallas, interpret=_interpret())
             if use_kernel else rules_with_ref
         )
-        vals, pos = _profiled(
-            "rules_with",
-            lambda: member_fn(
+        with _phase("rules_with", "kernel", rows=rows):
+            vals, pos = member_fn(
                 arrays["support"], arrays["confidence"], arrays["lift"],
                 arrays["depth"], arrays["node_item"],
                 arrays["post_lo"], arrays["post_hi"],
-                plos_j, phis_j, jnp.asarray(qitems),
+                plos_j, phis_j, qitems_j,
                 k=int(k), metric=metric, min_depth=int(min_depth),
                 role=role,
                 **({"max_postings": arrays["max_postings"]}
                    if use_kernel else {}),
                 **_dequant_statics(arrays),
-            ),
-            rows=plos.shape[0], shape=(int(plos.shape[0]), int(k)),
-            live=live,
-        )
+            )
         back = arrays["dfs_to_node"]
-    inv_j = jnp.asarray(inv, jnp.int32)
-    vals = vals[inv_j]
-    pos = pos[inv_j]
-    if back.shape[0] == 0:
-        node = jnp.full_like(pos, -1)
-    else:
-        node = jnp.where(pos >= 0, back[jnp.maximum(pos, 0)], -1)
+    with _phase("rules_with", "expand"):
+        vals = vals[inv_j]
+        pos = pos[inv_j]
+        if back.shape[0] == 0:
+            node = jnp.full_like(pos, -1)
+        else:
+            node = jnp.where(pos >= 0, back[jnp.maximum(pos, 0)], -1)
     return {"values": vals, "node": node, "pos": pos}
 
 
@@ -1138,58 +1091,59 @@ def prefix_ranges(
 
     Returns ``(los int32[Q], his int32[Q], nodes int32[Q])``.
     """
-    rows = canonical_prefix_rows(
-        prefixes, getattr(trie, "item_rank", None)
-    )
-    q = len(rows)
-    width = max((len(r) for r in rows), default=0)
-    mat = np.full((q, max(width, 1)), -1, np.int32)
-    for i, r in enumerate(rows):
-        mat[i, : len(r)] = r
-    if dt is None:
-        dt = _cached_device_trie(trie)
-    if getattr(dt, "layout", "plain") == "compressed":
-        # Span-aware descent: positions ARE DFS indices on this layout,
-        # so the subtree range is [pos, pos + subtree_size[pos]) with no
-        # dfs_order gather at all.
-        n = dt.subtree_size.shape[0]
-        pos = jnp.zeros((q,), jnp.int32)
-        rem = jnp.zeros((q,), jnp.int32)
-        ctail = jnp.zeros((q,), jnp.int32)
-        okm = jnp.ones((q,), bool)
+    with _phase("top_k", "prefix_ranges"):
+        rows = canonical_prefix_rows(
+            prefixes, getattr(trie, "item_rank", None)
+        )
+        q = len(rows)
+        width = max((len(r) for r in rows), default=0)
+        mat = np.full((q, max(width, 1)), -1, np.int32)
+        for i, r in enumerate(rows):
+            mat[i, : len(r)] = r
+        if dt is None:
+            dt = _cached_device_trie(trie)
+        if getattr(dt, "layout", "plain") == "compressed":
+            # Span-aware descent: positions ARE DFS indices on this layout,
+            # so the subtree range is [pos, pos + subtree_size[pos]) with no
+            # dfs_order gather at all.
+            n = dt.subtree_size.shape[0]
+            pos = jnp.zeros((q,), jnp.int32)
+            rem = jnp.zeros((q,), jnp.int32)
+            ctail = jnp.zeros((q,), jnp.int32)
+            okm = jnp.ones((q,), bool)
+            for c in range(width):
+                col = jnp.asarray(mat[:, c])
+                p2, r2, t2, hit = compressed_step(dt, pos, rem, ctail, col)
+                # only -1 is padding; other negatives are live (absent) items
+                live = col != -1
+                active = live & okm
+                okm = jnp.where(active, hit, okm)
+                adv = active & hit
+                pos = jnp.where(adv, p2, pos)
+                rem = jnp.where(adv, r2, rem)
+                ctail = jnp.where(adv, t2, ctail)
+            los = jnp.where(okm, pos, 0).astype(jnp.int32)
+            his = jnp.where(
+                okm, pos + dt.subtree_size[pos], 0
+            ).astype(jnp.int32)
+            his = jnp.minimum(his, n)
+            nodes = jnp.where(okm, dt.dfs_to_node[pos], -1)
+            return los, his, nodes
+        n = dt.dfs_order.shape[0]
+        nodes = jnp.zeros((q,), jnp.int32)
         for c in range(width):
             col = jnp.asarray(mat[:, c])
-            p2, r2, t2, hit = compressed_step(dt, pos, rem, ctail, col)
+            step = child_lookup(dt, nodes, col)
             # only -1 is padding; other negatives are live (absent) items
-            live = col != -1
-            active = live & okm
-            okm = jnp.where(active, hit, okm)
-            adv = active & hit
-            pos = jnp.where(adv, p2, pos)
-            rem = jnp.where(adv, r2, rem)
-            ctail = jnp.where(adv, t2, ctail)
-        los = jnp.where(okm, pos, 0).astype(jnp.int32)
+            nodes = jnp.where(col != -1, step, nodes)
+        ok = nodes >= 0
+        nid = jnp.maximum(nodes, 0)
+        los = jnp.where(ok, dt.dfs_order[nid], 0).astype(jnp.int32)
         his = jnp.where(
-            okm, pos + dt.subtree_size[pos], 0
+            ok, los + dt.subtree_size[nid], 0
         ).astype(jnp.int32)
         his = jnp.minimum(his, n)
-        nodes = jnp.where(okm, dt.dfs_to_node[pos], -1)
-        return los, his, nodes
-    n = dt.dfs_order.shape[0]
-    nodes = jnp.zeros((q,), jnp.int32)
-    for c in range(width):
-        col = jnp.asarray(mat[:, c])
-        step = child_lookup(dt, nodes, col)
-        # only -1 is padding; other negatives are live (absent) items
-        nodes = jnp.where(col != -1, step, nodes)
-    ok = nodes >= 0
-    nid = jnp.maximum(nodes, 0)
-    los = jnp.where(ok, dt.dfs_order[nid], 0).astype(jnp.int32)
-    his = jnp.where(
-        ok, los + dt.subtree_size[nid], 0
-    ).astype(jnp.int32)
-    his = jnp.minimum(his, n)
-    return los, his, jnp.where(ok, nodes, -1)
+        return los, his, jnp.where(ok, nodes, -1)
 
 
 def top_k_rules_batch(
@@ -1225,11 +1179,14 @@ def top_k_rules_batch(
     item_rank = getattr(
         plan.frozen if plan is not None else trie, "item_rank", None
     )
-    if not isinstance(prefixes, np.ndarray):
-        prefixes = list(prefixes)   # normalize once (generators included)
-    validate_prefixes(
-        prefixes, "top_k_rules_batch", item_rank=item_rank, strict=strict,
-    )
+    with _phase("top_k", "prep"):
+        if not isinstance(prefixes, np.ndarray):
+            # normalize once (generators included)
+            prefixes = list(prefixes)
+        validate_prefixes(
+            prefixes, "top_k_rules_batch", item_rank=item_rank,
+            strict=strict,
+        )
     stream = _as_streaming(trie)
     if stream is not None:
         if arrays is not None or not use_kernel:
@@ -1254,16 +1211,13 @@ def top_k_rules_batch(
             sharded_top_k_rules_batch,
         )
 
-        return _profiled(
-            "top_k",
-            lambda: sharded_top_k_rules_batch(
+        with _phase("top_k", "kernel", rows=len(prefixes)):
+            return sharded_top_k_rules_batch(
                 plan, prefixes, k, metric=metric, min_depth=min_depth,
-            ),
-            rows=len(prefixes), shape=(len(prefixes), int(k)),
-            n_shards=plan.n_shards,
-        )
+            )
     if arrays is None:
-        arrays = dfs_rank_arrays(trie)
+        with _phase("top_k", "prep"):
+            arrays = dfs_rank_arrays(trie)
     if len(prefixes) == 0:
         return {
             "values": jnp.zeros((0, max(int(k), 0)), jnp.float32),
@@ -1277,19 +1231,17 @@ def top_k_rules_batch(
         functools.partial(topk_rank_batch_pallas, interpret=_interpret())
         if use_kernel else topk_rank_batch_ref
     )
-    vals, pos = _profiled(
-        "top_k",
-        lambda: rank_fn(
+    with _phase("top_k", "kernel", rows=len(prefixes)):
+        vals, pos = rank_fn(
             arrays["support"], arrays["confidence"], arrays["lift"],
             arrays["depth"], los, his,
             k=int(k), metric=metric, min_depth=int(min_depth),
             **_dequant_statics(arrays),
-        ),
-        rows=len(prefixes), shape=(len(prefixes), int(k)),
-    )
-    node_ids = jnp.where(
-        pos >= 0, arrays["dfs_to_node"][jnp.maximum(pos, 0)], -1
-    )
+        )
+    with _phase("top_k", "expand"):
+        node_ids = jnp.where(
+            pos >= 0, arrays["dfs_to_node"][jnp.maximum(pos, 0)], -1
+        )
     return {"values": vals, "node": node_ids, "dfs_pos": pos}
 
 
@@ -1336,15 +1288,16 @@ def rule_search_batch(
         )
     plan = _as_shard_plan(trie)
     if ant_len is None and not isinstance(queries, np.ndarray):
-        queries = list(queries)
-        validate_rule_pairs(
-            queries, "rule_search_batch",
-            item_rank=getattr(
-                plan.frozen if plan is not None else trie,
-                "item_rank", None,
-            ),
-            strict=strict,
-        )
+        with _phase("rule_search", "prep"):
+            queries = list(queries)
+            validate_rule_pairs(
+                queries, "rule_search_batch",
+                item_rank=getattr(
+                    plan.frozen if plan is not None else trie,
+                    "item_rank", None,
+                ),
+                strict=strict,
+            )
     if plan is not None:
         if edges is not None:
             raise ValueError(
@@ -1358,11 +1311,8 @@ def rule_search_batch(
 
         n_q = (queries.shape[0] if isinstance(queries, np.ndarray)
                else len(queries))
-        return _profiled(
-            "rule_search",
-            lambda: sharded_rule_search_batch(plan, queries, ant_len),
-            rows=n_q, shape=(n_q,), n_shards=plan.n_shards,
-        )
+        with _phase("rule_search", "kernel", rows=n_q):
+            return sharded_rule_search_batch(plan, queries, ant_len)
     if ant_len is None:
         canonicalize = getattr(trie, "canonicalize_queries", None)
         if canonicalize is None:
@@ -1377,15 +1327,18 @@ def rule_search_batch(
                 trie, np.zeros((0, 1), np.int32), np.zeros((0,), np.int32),
                 edges=edges,
             )
-        ants = [p[0] for p in pairs]
-        cons = [p[1] for p in pairs]
-        queries, ant_len = canonicalize(ants, cons)
-    uq, ual, inv = dedup_query_rows(queries, ant_len)
+    with _phase("rule_search", "prep"):
+        if ant_len is None:
+            queries, ant_len = canonicalize(
+                [p[0] for p in pairs], [p[1] for p in pairs]
+            )
+        uq, ual, inv = dedup_query_rows(queries, ant_len)
+        inv_j = None if inv is None else jnp.asarray(inv)
     out = rule_search(trie, uq, ual, edges=edges)
-    if inv is None:
+    if inv_j is None:
         return out
-    inv_j = jnp.asarray(inv)
-    return {key: v[inv_j] for key, v in out.items()}
+    with _phase("rule_search", "expand"):
+        return {key: v[inv_j] for key, v in out.items()}
 
 
 # ----------------------------------------------------------------------
@@ -1394,18 +1347,15 @@ def rule_search_batch(
 def trie_reduce(trie) -> Dict[str, jax.Array]:
     dq = _dequant_statics(trie)
     n_nodes = int(trie.support.shape[0])
-    n, sup_sum, conf_max, conf_sum = _profiled(
-        "trie_reduce",
-        lambda: trie_reduce_pallas(
+    with _phase("trie_reduce", "kernel", rows=n_nodes):
+        n, sup_sum, conf_max, conf_sum = trie_reduce_pallas(
             jnp.asarray(trie.support),
             jnp.asarray(trie.confidence),
             jnp.asarray(trie.node_depth),
             interpret=_interpret(),
             n_transactions=dq["n_transactions"],
             confidence_scale=dq["confidence_scale"],
-        ),
-        rows=n_nodes, shape=(n_nodes,),
-    )
+        )
     return {
         "n_rules": n,
         "support_sum": sup_sum,

@@ -1,16 +1,16 @@
-"""Observability for the serve/query stack: metrics, spans, kernel rings.
+"""Observability for the serve/query stack: metrics and spans.
 
 One :class:`Observability` bundle travels with a scheduler/engine pair:
 
 * ``metrics`` — a :class:`~repro.obs.metrics.MetricsRegistry` (always
   on by default; counter cost matches the old ``stats`` dict it
   replaced),
-* ``tracer`` — a :class:`~repro.obs.trace.Tracer` (off by default;
-  enable with ``Observability(tracing=True)`` or ``obs.tracer.enabled
-  = True``),
-* ``profiler`` — the module-wide
-  :data:`~repro.obs.profile.kernel_profiler` (off by default; scope it
-  on with ``obs.profile_kernels()``).
+* ``tracer`` — a :class:`~repro.obs.trace.Tracer` (its span list is
+  off by default; enable with ``Observability(tracing=True)`` or
+  ``obs.tracer.enabled = True``).  Its scoped spans are always
+  ``serve.*`` annotations of a ``jax.profiler`` trace, and
+  ``kernels.ops`` adds ``ops.<op>.<phase>`` ones (:func:`scope`), so a
+  profiler session shows them on the device ops' timeline.
 
 The scheduler binds its clock seam into the tracer (``bind_clock``), so
 ``VirtualClock`` replays produce deterministic traces, and exports go
@@ -34,27 +34,20 @@ from .metrics import (
     merge_snapshots,
     quantile_from_snapshot,
 )
-from .profile import KernelProfiler, LaunchRecord, kernel_profiler
-from .trace import NULL_SPAN, Span, Tracer
+from .trace import NULL_SPAN, Span, Tracer, scope
 
 
 class Observability:
-    """Metrics + tracer + kernel profiler, bundled per serve component."""
+    """Metrics + tracer, bundled per serve component."""
 
-    def __init__(self, metrics=None, tracer=None, *, tracing=False,
-                 profiler=None):
+    def __init__(self, metrics=None, tracer=None, *, tracing=False):
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else Tracer(enabled=tracing)
-        self.profiler = profiler if profiler is not None else kernel_profiler
 
     def bind_clock(self, clock) -> None:
         """Point the tracer at a component's clock seam (first bind wins)."""
         if self.tracer.clock is None:
             self.tracer.clock = clock
-
-    def profile_kernels(self):
-        """Context manager: kernel-launch rings on, feeding ``metrics``."""
-        return self.profiler.enabled_scope(metrics=self.metrics)
 
     def snapshot(self) -> dict:
         return self.metrics.snapshot()
@@ -73,9 +66,7 @@ __all__ = [
     "Tracer",
     "Span",
     "NULL_SPAN",
-    "KernelProfiler",
-    "LaunchRecord",
-    "kernel_profiler",
+    "scope",
     "spans_to_trace_events",
     "write_trace",
     "metrics_text",

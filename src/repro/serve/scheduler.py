@@ -67,7 +67,7 @@ from repro.kernels.ops import (
     validate_rule_pairs,
 )
 from repro.kernels.tuning import launch_pad
-from repro.obs import Observability
+from repro.obs import Observability, scope
 from repro.serve.resilience import (
     MonotonicClock,
     ResilientTrieEngine,
@@ -113,6 +113,7 @@ class Request:
     tenant: str
     deadline_ms: float           # budget from submit; inf = none
     submit_s: float              # clock time at admission
+    batched_s: float = math.nan  # clock time its batch formed
     key: Tuple = ()              # canonical whole-query key (dedup+cache)
     bucket: Tuple = ()           # batchable group: (op, kwargs signature)
     canon: object = None         # canonical payload for batch assembly
@@ -138,6 +139,7 @@ class Response:
     cache_hit: bool = False
     retries: int = 0
     latency_ms: float = 0.0
+    queue_ms: float = math.nan   # submit to batch formed; nan: never batched
     error: str = ""
 
     @property
@@ -264,10 +266,6 @@ class TrieScheduler:
         self._g_cache = m.gauge("serve.cache_len")
         if getattr(self.engine, "obs", None) is None:
             self.engine.obs = self.obs
-        # measured kernel wall time (when the profiler is scoped on)
-        # becomes a queryable predictor bucket — see _observe_kernel
-        if self.obs.profiler is not None:
-            self.obs.profiler.add_observer(self._observe_kernel)
 
     @property
     def stats(self) -> Dict[str, int]:
@@ -275,13 +273,6 @@ class TrieScheduler:
         ``obs.metrics``).  Schema is stable: every key is pre-seeded at
         construction, including ``inserted``/``refreezes``."""
         return {k: c.value for k, c in self._c.items()}
-
-    def _observe_kernel(self, rec) -> None:
-        """Kernel-ring observer: measured launch wall time lands in a
-        ``("kernel", op)`` predictor bucket — disjoint from the
-        service-time buckets the deadline shaper reads, so profiling
-        never skews admission decisions."""
-        self.predictor.observe(("kernel", rec.op), rec.rows, rec.seconds)
 
     @property
     def frozen(self):
@@ -427,88 +418,93 @@ class TrieScheduler:
         Returns the responses completed by this step (possibly empty)."""
         done: List[Response] = []
         tr = self.obs.tracer
-        sroot = tr.start("step", parent=False)
-        try:
-            self._expire(done)
-            self._drain_inserts(done, parent=sroot)
-            if not self._pending:
+        with tr.span("step", parent=False) as sroot:
+            try:
+                return self._step(done, sroot)
+            finally:
+                self._g_pending.set(len(self._pending))
+                self._g_cache.set(len(self._cache))
+                tr.annotate(sroot, completed=len(done))
+
+    def _step(self, done: List[Response], sroot) -> List[Response]:
+        tr = self.obs.tracer
+        self._expire(done)
+        self._drain_inserts(done, parent=sroot)
+        if not self._pending:
+            return done
+
+        # shape one batch: the head request's bucket, arrival order
+        with tr.span("batch_form", parent=sroot) as bspan:
+            bucket = self._pending[0].bucket
+            batch: List[Request] = []
+            keep: deque = deque()
+            while self._pending:
+                r = self._pending.popleft()
+                if r.bucket == bucket and len(batch) < self.max_batch:
+                    batch.append(r)
+                else:
+                    keep.append(r)
+            self._pending = keep
+            self._stamp_batched(batch, bucket[0])
+            tr.annotate(bspan, op=bucket[0], batch=len(batch))
+            if tr.enabled:
+                for r in batch:
+                    tr.end(r.qspan)
+                    r.sspan = tr.start("serve", parent=r.span,
+                                       op=r.op)
+
+        with tr.span("dedup_cache", parent=sroot, op=bucket[0]):
+            # cache hits never touch the kernels
+            misses: List[Request] = []
+            for r in batch:
+                hit = self._cache_get(r.key)
+                if hit is not None:
+                    self._c["cache_hits"].inc()
+                    done.append(self._finish(r, self._respond_ok(
+                        r, hit, backend="cache", cache_hit=True,
+                    )))
+                else:
+                    misses.append(r)
+            if not misses:
                 return done
 
-            # shape one batch: the head request's bucket, arrival order
-            with tr.span("batch_form", parent=sroot) as bspan:
-                bucket = self._pending[0].bucket
-                batch: List[Request] = []
-                keep: deque = deque()
-                while self._pending:
-                    r = self._pending.popleft()
-                    if r.bucket == bucket and len(batch) < self.max_batch:
-                        batch.append(r)
-                    else:
-                        keep.append(r)
-                self._pending = keep
-                tr.annotate(bspan, op=bucket[0], batch=len(batch))
-                if tr.enabled:
-                    for r in batch:
-                        tr.end(r.qspan)
-                        r.sspan = tr.start("serve", parent=r.span,
-                                           op=r.op)
+            # whole-query dedup inside the batch
+            unique: "OrderedDict[Tuple, List[Request]]" = OrderedDict()
+            for r in misses:
+                unique.setdefault(r.key, []).append(r)
+            self._c["dedup_collapsed"].inc(len(misses) - len(unique))
 
-            with tr.span("dedup_cache", parent=sroot, op=bucket[0]):
-                # cache hits never touch the kernels
-                misses: List[Request] = []
-                for r in batch:
-                    hit = self._cache_get(r.key)
-                    if hit is not None:
-                        self._c["cache_hits"].inc()
-                        done.append(self._finish(r, self._respond_ok(
-                            r, hit, backend="cache", cache_hit=True,
+            # the deadline shaper: predicted service for THIS bucket
+            # shape — a request that cannot survive the launch times
+            # out now rather than riding (and slowing) a batch it
+            # will miss anyway
+            predicted_ms = self.predictor.predict_ms(
+                bucket, len(unique))
+            now = self.clock.now()
+            live: "OrderedDict[Tuple, List[Request]]" = OrderedDict()
+            for key, reqs in unique.items():
+                still = []
+                for r in reqs:
+                    if now + predicted_ms / 1e3 > r.expires_s():
+                        done.append(self._finish(r, Response(
+                            id=r.id, op=r.op, tenant=r.tenant,
+                            status=TIMEOUT,
+                            error=(
+                                f"predicted launch {predicted_ms:.1f}"
+                                f"ms busts deadline "
+                                f"{r.deadline_ms:.1f}ms"
+                            ),
+                            latency_ms=(now - r.submit_s) * 1e3,
                         )))
                     else:
-                        misses.append(r)
-                if not misses:
-                    return done
+                        still.append(r)
+                if still:
+                    live[key] = still
+            if not live:
+                return done
 
-                # whole-query dedup inside the batch
-                unique: "OrderedDict[Tuple, List[Request]]" = OrderedDict()
-                for r in misses:
-                    unique.setdefault(r.key, []).append(r)
-                self._c["dedup_collapsed"].inc(len(misses) - len(unique))
-
-                # the deadline shaper: predicted service for THIS bucket
-                # shape — a request that cannot survive the launch times
-                # out now rather than riding (and slowing) a batch it
-                # will miss anyway
-                predicted_ms = self.predictor.predict_ms(
-                    bucket, len(unique))
-                now = self.clock.now()
-                live: "OrderedDict[Tuple, List[Request]]" = OrderedDict()
-                for key, reqs in unique.items():
-                    still = []
-                    for r in reqs:
-                        if now + predicted_ms / 1e3 > r.expires_s():
-                            done.append(self._finish(r, Response(
-                                id=r.id, op=r.op, tenant=r.tenant,
-                                status=TIMEOUT,
-                                error=(
-                                    f"predicted launch {predicted_ms:.1f}"
-                                    f"ms busts deadline "
-                                    f"{r.deadline_ms:.1f}ms"
-                                ),
-                                latency_ms=(now - r.submit_s) * 1e3,
-                            )))
-                        else:
-                            still.append(r)
-                    if still:
-                        live[key] = still
-                if not live:
-                    return done
-
-            done.extend(self._launch(bucket, live, parent=sroot))
-            return done
-        finally:
-            self._g_pending.set(len(self._pending))
-            self._g_cache.set(len(self._cache))
-            tr.end(sroot, completed=len(done))
+        done.extend(self._launch(bucket, live, parent=sroot))
+        return done
 
     def _drain_inserts(self, done: List[Response], parent=None) -> None:
         """Apply every pending insert, in arrival order, before any
@@ -528,6 +524,7 @@ class TrieScheduler:
                 r = self._pending.popleft()
                 (inserts if r.op == "insert" else keep).append(r)
             self._pending = keep
+            self._stamp_batched(inserts, "insert")
             tr.annotate(dspan, n=len(inserts))
             if tr.enabled:
                 for r in inserts:
@@ -556,6 +553,17 @@ class TrieScheduler:
                     fspan, folded=0 if folded is None else int(folded))
             if folded is not None:
                 self._c["refreezes"].inc()
+
+    def _stamp_batched(self, batch: List[Request], op: str) -> None:
+        """Stamp each request with the time its batch formed (one clock
+        read) and observe its queue wait, submit to that time, into
+        ``serve.queue_wait_ms{op=...}``; ``_finish`` copies it onto the
+        response as ``queue_ms``."""
+        now = self.clock.now()
+        hist = self.obs.metrics.histogram("serve.queue_wait_ms", op=op)
+        for r in batch:
+            r.batched_s = now
+            hist.observe((now - r.submit_s) * 1e3)
 
     def drain(self, max_steps: int = 100000) -> List[Response]:
         """Step until the queue is empty; returns responses in completion
@@ -730,7 +738,9 @@ class TrieScheduler:
 
     @staticmethod
     def _slice_rows(op: str, result: Dict, n: int) -> List[Dict]:
-        host = {k: np.asarray(v) for k, v in result.items()}
+        # the device-to-host pull: where the host waits on the chip
+        with scope("serve.fetch"):
+            host = {k: np.asarray(v) for k, v in result.items()}
         return [
             {k: v[i] for k, v in host.items()} for i in range(n)
         ]
@@ -750,6 +760,7 @@ class TrieScheduler:
         )
 
     def _finish(self, r: Request, resp: Response) -> Response:
+        resp.queue_ms = (r.batched_s - r.submit_s) * 1e3
         self._c[resp.status].inc()
         m = self.obs.metrics
         m.counter("serve.requests", tenant=r.tenant,

@@ -18,7 +18,6 @@ from repro.obs import (
     NULL_INSTRUMENT,
     NULL_SPAN,
     Histogram,
-    KernelProfiler,
     MetricsRegistry,
     Observability,
     Tracer,
@@ -337,55 +336,194 @@ def test_per_tenant_labels_cover_workload(fz, replicated):
 
 
 # ----------------------------------------------------------------------
-# kernel-launch profiling
+# spans in the jax.profiler trace: serve.* over ops.*, on one thread
 # ----------------------------------------------------------------------
-def test_kernel_profiler_rings_metrics_and_predictor_feed(fz, replicated):
+ENGINE_OP = {"rule_search": "rule_search_batch", "top_k": "top_k_rules_batch",
+             "rules_with": "rules_with"}
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["tracing", "no_tracing"])
+def profiled_steps(request, fz, replicated, tmp_path_factory):
+    """Annotations of the loop thread while a small scheduler serves one
+    batch of each op under ``jax.profiler``, with the in-memory tracer
+    on or off: ``[(name, start_ns, end_ns)]`` sorted by start."""
+    import jax
+    from jax.profiler import ProfileData
+
     replicated.obs = None
-    obs = Observability(tracing=False)
-    sched = TrieScheduler(replicated, clock=VirtualClock(), obs=obs)
-    prof = obs.profiler
-    prof.clear()
-    assert not prof.enabled                       # off by default
-    with obs.profile_kernels():
-        sched.submit("rules_with", 0, {"k": 3})
-        sched.submit("top_k", [], {"k": 3})
-        sched.drain()
-    assert not prof.enabled                       # scope restores
-    assert {"rules_with", "top_k"} <= set(prof.ops())
-    rec = prof.ring("rules_with")[-1]
-    assert rec.rows >= 1 and rec.seconds >= 0
-    assert rec.pad_factor >= 1.0 and rec.n_shards == 1
-    # records mirrored into the registry...
-    assert obs.metrics.value("kernel.launches", op="rules_with") >= 1
-    lm = obs.metrics.histogram("kernel.launch_ms", op="rules_with")
-    assert lm.count >= 1
-    # ...and fed to the launch predictor under a ("kernel", op) bucket,
-    # disjoint from the service-time buckets the batch shaper reads
-    assert any(
-        key[:2] == ("kernel", "rules_with")
-        for key in sched.predictor._ewma_ms
-    )
-    # outside the scope nothing records
-    before = len(prof.ring("rules_with"))
-    sched.submit("rules_with", 0, {"k": 4})
+    sched = TrieScheduler(replicated, clock=VirtualClock(),
+                          obs=Observability(tracing=request.param))
+    wl = zipfian_workload(fz, 30, seed=3)
+    for w in wl:                              # compile outside the trace
+        sched.submit(w["op"], w["payload"], w["kwargs"])
     sched.drain()
-    assert len(prof.ring("rules_with")) == before
+    sched = TrieScheduler(replicated, clock=VirtualClock(), cache_size=0,
+                          obs=Observability(tracing=request.param))
+    for w in wl:
+        sched.submit(w["op"], w["payload"], w["kwargs"])
+    out_dir = tmp_path_factory.mktemp("profile")
+    jax.profiler.start_trace(str(out_dir))
+    try:
+        out = sched.drain()
+    finally:
+        jax.profiler.stop_trace()
+    assert all(r.ok for r in out)
+    pb = sorted(out_dir.rglob("*.xplane.pb"))[-1]
+    lines = []
+    for plane in ProfileData.from_file(str(pb)).planes:
+        for line in plane.lines:
+            evs = [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+                   for e in line.events
+                   if e.name.startswith(("serve.", "ops."))]
+            if evs:
+                lines.append(sorted(evs, key=lambda e: e[1]))
+    assert len(lines) == 1, "annotations on more than one thread"
+    return lines[0]
 
 
-def test_kernel_profiler_ring_capacity_and_dead_observer():
-    prof = KernelProfiler(capacity=4)
-    calls = []
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
 
-    def spy(rec):
-        calls.append(rec.op)
 
-    prof.add_observer(spy)
-    prof.enable()
-    for i in range(10):
-        prof.record("op", rows=1, shape=(1,), seconds=0.001)
-    assert len(prof.ring("op")) == 4              # ring, not a log
-    assert len(calls) == 10
-    del spy                                       # weakly held: drops
-    prof.record("op", rows=1, shape=(1,), seconds=0.001)
-    assert len(calls) == 10
-    prof.disable()
+def _parents(ev, events, prefix):
+    return [o for o in events if o is not ev and o[0].startswith(prefix)
+            and _inside(ev, o)]
+
+
+def test_profiler_trace_nests_serve_and_ops_spans(profiled_steps):
+    evs = profiled_steps
+    names = {e[0] for e in evs}
+    assert {"serve.step", "serve.batch_form", "serve.dedup_cache",
+            "serve.launch", "serve.merge", "serve.fetch"} <= names
+    for op, eop in ENGINE_OP.items():
+        assert f"serve.engine.{eop}" in names
+        assert {f"ops.{op}.prep", f"ops.{op}.kernel"} <= names
+    assert "ops.top_k.prefix_ranges" in names
+    # serve.step ⊃ serve.launch ⊃ serve.engine.* ⊃ ops.*
+    for ev in evs:
+        if ev[0] == "serve.launch":
+            assert _parents(ev, evs, "serve.step")
+        elif ev[0].startswith("serve.engine."):
+            assert _parents(ev, evs, "serve.launch")
+        elif ev[0].startswith("ops."):
+            eng = _parents(ev, evs, "serve.engine.")
+            assert len(eng) == 1
+            assert _parents(eng[0], evs, "serve.launch")
+        elif ev[0] == "serve.fetch":
+            assert _parents(ev, evs, "serve.merge")
+
+
+def test_ops_annotations_never_nest(profiled_steps):
+    ops_evs = [e for e in profiled_steps if e[0].startswith("ops.")]
+    assert ops_evs
+    for ev in ops_evs:
+        assert not _parents(ev, ops_evs, "ops.")
+    # each op's phases run in order: prep and descent before its kernel,
+    # expand after it
+    order = {"prep": 0, "prefix_ranges": 1, "kernel": 2, "expand": 3}
+    for eng in (e for e in profiled_steps
+                if e[0].startswith("serve.engine.")):
+        phases = [order[e[0].rsplit(".", 1)[1]] for e in ops_evs
+                  if _inside(e, eng)]
+        assert phases == sorted(phases)
+
+
+def test_tracer_span_annotates_when_disabled(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("outer", op="x"):
+            with tr.span("inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert tr.spans == []                          # list stays empty
+    pb = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    names = [e.name for p in ProfileData.from_file(str(pb)).planes
+             for ln in p.lines for e in ln.events]
+    assert names.count("serve.outer") == 1
+    assert names.count("serve.inner") == 1
+
+
+# ----------------------------------------------------------------------
+# queue wait: submit to the time the request's batch formed
+# ----------------------------------------------------------------------
+def test_queue_ms_is_submit_to_batch_formed(fz, replicated):
+    replicated.obs = None
+    clock = VirtualClock()
+    sched = TrieScheduler(replicated, clock=clock)
+    h = sched.submit("rules_with", 0, {"k": 3})
+    clock.advance(0.005)
+    (r,) = sched.step()
+    assert r.ok and not r.cache_hit
+    assert r.queue_ms == pytest.approx(5.0, abs=1e-9)
+    # a cache hit is batched too, and gets its own wait
+    h2 = sched.submit("rules_with", 0, {"k": 3})
+    clock.advance(0.002)
+    (r2,) = sched.step()
+    assert r2.ok and r2.cache_hit and h2.id != h.id
+    assert r2.queue_ms == pytest.approx(2.0, abs=1e-9)
+
+
+def test_queue_ms_waits_behind_other_buckets(fz, replicated):
+    """One bucket per step: a request of another op waits a step."""
+    replicated.obs = None
+    clock = VirtualClock()
+    sched = TrieScheduler(replicated, clock=clock, cache_size=0)
+    sched.submit("rules_with", 0, {"k": 3})
+    sched.submit("top_k", [], {"k": 3})
+    clock.advance(0.001)
+    (first,) = sched.step()
+    clock.advance(0.004)
+    (second,) = sched.step()
+    assert first.op == "rules_with" and second.op == "top_k"
+    assert first.queue_ms == pytest.approx(1.0, abs=1e-9)
+    assert second.queue_ms == pytest.approx(5.0, abs=1e-9)
+    assert second.latency_ms == pytest.approx(5.0, abs=1e-9)
+
+
+def test_queue_ms_nan_when_never_batched(fz, replicated):
+    replicated.obs = None
+    clock = VirtualClock()
+    sched = TrieScheduler(replicated, clock=clock, max_pending=1,
+                          shed_policy="drop_oldest")
+    sched.submit("rules_with", 0, {"k": 3}, deadline_ms=1.0)
+    sched.submit("rules_with", 1, {"k": 3}, deadline_ms=1.0)   # sheds
+    clock.advance(0.002)                                        # expires
+    out = sched.step()
+    statuses = sorted(r.status for r in sched.responses.values())
+    assert statuses == ["shed", "timeout"] and len(out) == 1
+    assert all(math.isnan(r.queue_ms) for r in sched.responses.values())
+    # nothing was batched, so the histogram saw nothing
+    m = sched.obs.metrics
+    assert sum(h.count for h in m.histograms_named(
+        "serve.queue_wait_ms")) == 0
+
+
+def test_queue_wait_histogram_counts_each_batched_request_once(
+        fz, replicated):
+    replicated.obs = None
+    clock = VirtualClock()
+    sched = TrieScheduler(replicated, clock=clock, max_batch=4)
+    wl = zipfian_workload(fz, 40, seed=11)
+    for w in wl:
+        sched.submit(w["op"], w["payload"], w["kwargs"],
+                     deadline_ms=math.inf)
+        clock.advance(0.0005)
+    out = sched.drain()
+    assert len(out) == len(wl) and all(r.ok for r in out)
+    m = sched.obs.metrics
+    by_op = {}
+    for h in m.histograms_named("serve.queue_wait_ms"):
+        by_op[dict(h.labels)["op"]] = h
+    for op in {w["op"] for w in wl}:
+        want = sorted(r.queue_ms for r in out if r.op == op)
+        h = by_op[op]
+        assert h.count == len(want)
+        assert h.total == pytest.approx(sum(want))
+        assert h.max == pytest.approx(max(want))
+    assert sum(h.count for h in by_op.values()) == len(wl)
