@@ -1197,17 +1197,19 @@ def compressed_step(
 def compressed_descend(trie: DeviceTrie, queries: jax.Array):
     """Resolve padded item rows to DFS positions on a compressed trie.
 
-    queries: int32[Q, L] frequency-canonical rows, -1 padded.  Returns
-    ``(pos int32[Q], found bool[Q])`` — the position of the node spelling
-    the full row (root for all-padding rows).  The compressed analog of
-    a ``child_lookup`` fold; ``ops.prefix_ranges`` builds subtree ranges
-    from it via the position-space ``subtree_size``.
+    queries: int32[Q, L] frequency-canonical rows, -1 padded.  Only -1
+    is padding: any other negative id is a live item no edge carries, so
+    its row is not found.  Returns ``(pos int32[Q], found bool[Q])`` —
+    the position of the node spelling the full row (root for
+    all-padding rows).  The compressed analog of a ``child_lookup``
+    fold; ``ops.prefix_ranges`` builds subtree ranges from it via the
+    position-space ``subtree_size``.
     """
     q = queries.shape[0]
 
     def step(carry, items):
         pos, rem, ctail, ok = carry
-        active = (items >= 0) & ok
+        active = (items != -1) & ok
         pos2, rem2, ctail2, hit = compressed_step(trie, pos, rem, ctail, items)
         ok = jnp.where(active, hit, ok)
         adv = active & hit

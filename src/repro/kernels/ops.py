@@ -21,7 +21,7 @@ from repro.core.array_trie import (
     DeviceTrie,
     canonical_prefix_rows,
     child_lookup,
-    compressed_step,
+    compressed_descend,
     sanitize_query_items,
 )
 
@@ -99,9 +99,10 @@ def _phase(op: str, phase: str, **attrs):
     around one phase of an op, so a ``jax.profiler`` trace splits its
     host time.  Phases are siblings, never nested: ``prep`` (host numpy
     work and uploads before the launch), ``prefix_ranges`` (``top_k``'s
-    per-column eager descent), ``kernel`` (the dispatch of the jitted or
-    Pallas program; ``rows`` its launch rows) and ``expand`` (the eager
-    gathers after it, back to query rows and node ids)."""
+    host canonicalization and the dispatch of its jitted descent),
+    ``kernel`` (the dispatch of the jitted or Pallas program; ``rows``
+    its launch rows) and ``expand`` (the eager gathers after it, back to
+    query rows and node ids)."""
     return _scope(f"ops.{op}.{phase}", **attrs)
 
 
@@ -1074,12 +1075,14 @@ def prefix_ranges(
 ) -> tuple:
     """Resolve Q antecedent prefixes to DFS ranges in one batched descent.
 
-    Prefixes are canonicalized to frequency order when the trie carries
-    an ``item_rank`` table, padded to ``[Q, P]``, and walked root-down
-    via the CSR ``child_lookup`` — one vectorized step per column, all
-    queries at once.  Absent prefixes (invalid item ids included)
-    resolve to the empty range ``[0, 0)``; empty prefixes to the whole
-    trie ``[0, N)``.
+    Prefixes are canonicalized to frequency order on the host when the
+    trie carries an ``item_rank`` table and packed into one int32
+    ``[Q, P]`` matrix (a matrix input keeps its width ``P``), which
+    ``_descend_ranges`` walks root-down in one jitted program — the CSR
+    ``child_lookup`` on the plain layout, the span-aware
+    ``compressed_descend`` on the compressed one — all queries at once.
+    Absent prefixes (invalid item ids included) resolve to the empty
+    range ``[0, 0)``; empty prefixes to the whole trie ``[0, N)``.
 
     In an already-padded ``[Q, P]`` MATRIX, ``-1`` entries are padding
     (the repo-wide query-matrix convention) and are dropped per row; in
@@ -1095,55 +1098,51 @@ def prefix_ranges(
         rows = canonical_prefix_rows(
             prefixes, getattr(trie, "item_rank", None)
         )
-        q = len(rows)
         width = max((len(r) for r in rows), default=0)
-        mat = np.full((q, max(width, 1)), -1, np.int32)
+        if isinstance(prefixes, np.ndarray) and prefixes.ndim == 2:
+            # a matrix keeps its own width, so one launch shape is one
+            # program however long its prefixes happen to be
+            width = max(width, prefixes.shape[1])
+        mat = np.full((len(rows), max(width, 1)), -1, np.int32)
         for i, r in enumerate(rows):
             mat[i, : len(r)] = r
         if dt is None:
             dt = _cached_device_trie(trie)
-        if getattr(dt, "layout", "plain") == "compressed":
-            # Span-aware descent: positions ARE DFS indices on this layout,
-            # so the subtree range is [pos, pos + subtree_size[pos]) with no
-            # dfs_order gather at all.
-            n = dt.subtree_size.shape[0]
-            pos = jnp.zeros((q,), jnp.int32)
-            rem = jnp.zeros((q,), jnp.int32)
-            ctail = jnp.zeros((q,), jnp.int32)
-            okm = jnp.ones((q,), bool)
-            for c in range(width):
-                col = jnp.asarray(mat[:, c])
-                p2, r2, t2, hit = compressed_step(dt, pos, rem, ctail, col)
-                # only -1 is padding; other negatives are live (absent) items
-                live = col != -1
-                active = live & okm
-                okm = jnp.where(active, hit, okm)
-                adv = active & hit
-                pos = jnp.where(adv, p2, pos)
-                rem = jnp.where(adv, r2, rem)
-                ctail = jnp.where(adv, t2, ctail)
-            los = jnp.where(okm, pos, 0).astype(jnp.int32)
-            his = jnp.where(
-                okm, pos + dt.subtree_size[pos], 0
-            ).astype(jnp.int32)
-            his = jnp.minimum(his, n)
-            nodes = jnp.where(okm, dt.dfs_to_node[pos], -1)
-            return los, his, nodes
-        n = dt.dfs_order.shape[0]
-        nodes = jnp.zeros((q,), jnp.int32)
-        for c in range(width):
-            col = jnp.asarray(mat[:, c])
-            step = child_lookup(dt, nodes, col)
-            # only -1 is padding; other negatives are live (absent) items
-            nodes = jnp.where(col != -1, step, nodes)
-        ok = nodes >= 0
-        nid = jnp.maximum(nodes, 0)
-        los = jnp.where(ok, dt.dfs_order[nid], 0).astype(jnp.int32)
-        his = jnp.where(
-            ok, los + dt.subtree_size[nid], 0
-        ).astype(jnp.int32)
-        his = jnp.minimum(his, n)
-        return los, his, jnp.where(ok, nodes, -1)
+        return _descend_ranges(dt, mat)
+
+
+@jax.jit
+def _descend_ranges(dt: DeviceTrie, mat: jax.Array):
+    """``prefix_ranges``' descent: one program per ``(Q, P, layout)``.
+
+    ``mat`` is the canonical int32 ``[Q, P]`` prefix matrix.  Only ``-1``
+    is padding; any other negative id is a live item that no edge
+    carries, so its row resolves to the empty range.  A ``lax.scan``
+    folds the columns root-down (``compressed_descend`` on the compressed
+    layout), then the subtree gathers close each row's range (``his``
+    clamped to ``N``, ``nodes`` -1 where absent).
+    """
+    if dt.layout == "compressed":
+        pos, ok = compressed_descend(dt, mat)
+        # Positions ARE DFS indices on this layout, so the subtree range
+        # is [pos, pos + subtree_size[pos]) with no dfs_order gather.
+        n = dt.subtree_size.shape[0]
+        los = jnp.where(ok, pos, 0).astype(jnp.int32)
+        his = jnp.where(ok, pos + dt.subtree_size[pos], 0).astype(jnp.int32)
+        return los, jnp.minimum(his, n), jnp.where(
+            ok, dt.dfs_to_node[pos], -1
+        )
+
+    def step(nodes, col):
+        return jnp.where(col != -1, child_lookup(dt, nodes, col), nodes), None
+
+    nodes, _ = jax.lax.scan(step, jnp.zeros(mat.shape[:1], jnp.int32), mat.T)
+    n = dt.dfs_order.shape[0]
+    ok = nodes >= 0
+    nid = jnp.maximum(nodes, 0)
+    los = jnp.where(ok, dt.dfs_order[nid], 0).astype(jnp.int32)
+    his = jnp.where(ok, los + dt.subtree_size[nid], 0).astype(jnp.int32)
+    return los, jnp.minimum(his, n), jnp.where(ok, nodes, -1)
 
 
 def top_k_rules_batch(

@@ -10,17 +10,34 @@ counterpart (tie order included) and to its jnp oracle:
   empty prefixes,
 - ``rule_search_batch`` ≡ Q single ``rule_search`` calls on ragged
   (A, C) pairs,
+- ``prefix_ranges``' jitted descent ≡ the host mirror
+  (``host_prefix_ranges``) ≡ the eager column walk, on both layouts,
+  compiled once per launch shape,
 - everything on BOTH construction engines (``pointer`` freeze and
   ``arrays`` build) — the indexes must answer identically.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
+from jax import monitoring
 
-from repro.core.synthetic import synthetic_csr_trie
+from repro.core.array_trie import (
+    canonical_prefix_rows,
+    child_lookup,
+    compressed_step,
+)
+from repro.core.synthetic import (
+    frozen_from_arrays,
+    synthetic_chain_trie,
+    synthetic_csr_trie,
+)
+from repro.distributed.trie_sharding import host_prefix_ranges
 from repro.kernels.item_index import ROLES, rules_with_pallas
 from repro.kernels.metrics_inkernel import RANK_METRICS
 from repro.kernels.ops import (
+    _descend_ranges,
     item_rank_arrays,
     prefix_ranges,
     rule_search,
@@ -430,3 +447,162 @@ def test_batched_ops_pointer_vs_arrays_engine(mined):
     a = top_k_rules_batch(fz_ptr, prefixes, 5, "confidence")
     b = top_k_rules_batch(fz_arr, prefixes, 5, "confidence")
     _assert_same(a, b, ("values", "node", "dfs_pos"))
+
+
+# ----------------------------------------------------------------------
+# prefix_ranges: the jitted descent ≡ the host mirror ≡ the eager walk
+# ----------------------------------------------------------------------
+def _eager_prefix_ranges(dt, mat):
+    """A column-by-column eager descent (one dispatch per step), the
+    layout-aware oracle for the jitted ``_descend_ranges``."""
+    q, width = mat.shape
+    if dt.layout == "compressed":
+        n = dt.subtree_size.shape[0]
+        pos = jnp.zeros((q,), jnp.int32)
+        rem = jnp.zeros((q,), jnp.int32)
+        ctail = jnp.zeros((q,), jnp.int32)
+        okm = jnp.ones((q,), bool)
+        for c in range(width):
+            col = jnp.asarray(mat[:, c])
+            p2, r2, t2, hit = compressed_step(dt, pos, rem, ctail, col)
+            active = (col != -1) & okm
+            okm = jnp.where(active, hit, okm)
+            adv = active & hit
+            pos = jnp.where(adv, p2, pos)
+            rem = jnp.where(adv, r2, rem)
+            ctail = jnp.where(adv, t2, ctail)
+        los = jnp.where(okm, pos, 0)
+        his = jnp.minimum(jnp.where(okm, pos + dt.subtree_size[pos], 0), n)
+        return los, his, jnp.where(okm, dt.dfs_to_node[pos], -1)
+    n = dt.dfs_order.shape[0]
+    nodes = jnp.zeros((q,), jnp.int32)
+    for c in range(width):
+        col = jnp.asarray(mat[:, c])
+        nodes = jnp.where(col != -1, child_lookup(dt, nodes, col), nodes)
+    ok = nodes >= 0
+    nid = jnp.maximum(nodes, 0)
+    los = jnp.where(ok, dt.dfs_order[nid], 0)
+    his = jnp.minimum(jnp.where(ok, los + dt.subtree_size[nid], 0), n)
+    return los, his, jnp.where(ok, nodes, -1)
+
+
+@pytest.fixture(scope="module")
+def descent_stores(frozen):
+    """``{store: (FrozenTrie, DeviceTrie)}``: a chain-heavy synthetic
+    trie in both layouts (its paths are not frequency-sorted, so its
+    FrozenTrie carries no ``item_rank`` and rows descend as given), and
+    the mined paper trie, whose ``item_rank`` canonicalizes shuffled
+    prefixes."""
+    arrs = synthetic_chain_trie(2000, chain_fraction=0.75, seed=3)
+    fz = dataclasses.replace(frozen_from_arrays(arrs), item_rank=None)
+    assert fz.span_fraction() >= 0.5
+    dtc = fz.device_arrays(layout="compressed")
+    assert dtc.layout == "compressed"
+    paper = frozen(0.2)
+    return {
+        "plain": (fz, fz.device_arrays(layout="plain")),
+        "compressed": (fz, dtc),
+        "mined": (paper, paper.device_arrays()),
+    }
+
+
+def _path(fz, nid):
+    items = []
+    while nid > 0:
+        items.append(int(fz.node_item[nid]))
+        nid = int(fz.node_parent[nid])
+    return items[::-1]
+
+
+def _descent_matrix(rng, fz, q, width=8):
+    """``[q, width]`` prefix rows cycling through every row kind: real
+    path prefixes cut at any depth (mid-span on the compressed layout),
+    the scheduler's ``-2 - i`` pad rows, empty rows, out-of-vocab ids and
+    live negative ids after a real item."""
+    mat = np.full((q, width), -1, np.int32)
+    for i in range(q):
+        kind = i % 6
+        path = _path(fz, int(rng.randint(1, fz.n_nodes)))[:width]
+        if kind in (0, 1):
+            row = path[: rng.randint(1, len(path) + 1)] if kind == 0 else path
+            if fz.item_rank is not None:  # canonicalization reorders it
+                row = list(rng.permutation(row))
+        elif kind == 2:
+            row = [-2 - i]
+        elif kind == 3:
+            row = []
+        elif kind == 4:
+            row = path[:1] + [10 ** 6]
+        else:
+            row = path[:1] + [-5]
+        mat[i, : len(row)] = row
+    return mat
+
+
+@pytest.mark.parametrize("q", [1, 2, 4, 8, 16, 32, 64])
+@pytest.mark.parametrize("store", ["plain", "compressed", "mined"])
+def test_prefix_ranges_jitted_matches_mirror(store, q, descent_stores):
+    fz, dt = descent_stores[store]
+    rng = np.random.RandomState(q)
+    mat = _descent_matrix(rng, fz, q)
+    # ragged rows: -1 is a literal item there (remapped to -9, absent)
+    ragged = [list(r[r != -1]) for r in mat]
+    for i in range(0, q, 3):
+        ragged[i] = ragged[i] + [-1]
+    for prefixes in (ragged, mat):
+        got = [np.asarray(a) for a in prefix_ranges(fz, prefixes, dt=dt)]
+        want = host_prefix_ranges(fz, prefixes)
+        rows = canonical_prefix_rows(prefixes, fz.item_rank)
+        cmat = np.full((q, max(max(map(len, rows)), 1)), -1, np.int32)
+        for i, r in enumerate(rows):
+            cmat[i, : len(r)] = r
+        eager = [np.asarray(a) for a in _eager_prefix_ranges(dt, cmat)]
+        for name, g, w, e in zip(("los", "his", "nodes"), got, want, eager):
+            assert g.dtype == np.int32, name
+            np.testing.assert_array_equal(g, w, err_msg=f"{name} mirror")
+            np.testing.assert_array_equal(g, e, err_msg=f"{name} eager")
+    los, his, nodes = got  # the matrix form's
+    if q >= 8:
+        # the kinds resolved as they should: pads, out-of-vocab and live
+        # negatives to [0, 0), empty rows to [0, N), real paths found
+        kinds = np.arange(q) % 6
+        dead = np.isin(kinds, (2, 4, 5))
+        assert (los[dead] == 0).all() and (his[dead] == 0).all()
+        assert (nodes[dead] == -1).all()
+        assert (los[kinds == 3] == 0).all()
+        assert (his[kinds == 3] == fz.n_nodes).all()
+        if fz.item_rank is None:
+            assert (nodes[kinds <= 1] >= 0).all()
+    if store == "compressed" and q >= 32:
+        # some found rows end mid-span: on a single-child, non-root node
+        cc = np.bincount(fz.node_parent[1:], minlength=fz.n_nodes)
+        hit = nodes[nodes > 0]
+        assert (cc[hit] == 1).any()
+
+
+def test_prefix_ranges_compiles_once_per_shape(descent_stores):
+    """Two launches of one ``(Q, P)`` shape with different contents run
+    one compiled program: the second compiles nothing."""
+    fz, dt = descent_stores["compressed"]
+    compiles = []
+    live = [True]
+
+    def listen(event, duration, **_):
+        if live[0] and event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        rng = np.random.RandomState(7)
+        a = _descent_matrix(rng, fz, 5, width=7)
+        b = _descent_matrix(rng, fz, 5, width=7)
+        assert not (a == b).all()
+        before = _descend_ranges._cache_size()
+        ra = [np.asarray(x) for x in prefix_ranges(fz, a, dt=dt)]
+        first = len(compiles)
+        rb = [np.asarray(x) for x in prefix_ranges(fz, b, dt=dt)]
+        assert len(compiles) == first
+        assert _descend_ranges._cache_size() == before + 1
+        assert any((x != y).any() for x, y in zip(ra, rb))
+    finally:
+        live[0] = False

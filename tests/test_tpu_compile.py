@@ -21,7 +21,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.array_trie import DeviceTrie
 from repro.kernels.item_index import ROLES, rules_with_pallas
+from repro.kernels.ops import _descend_ranges
 from repro.kernels.rank import topk_rank_batch_pallas
 from repro.kernels.rule_search import rule_search_fused, rule_search_span
 from repro.kernels.support_count import support_count_pallas
@@ -30,6 +32,7 @@ from repro.kernels.trie_reduce import trie_reduce_pallas
 N = 1 << 20          # trie nodes (chip_smoke.py's scale phase)
 Q = 64               # queries per launch
 WIDTH = 16           # query-row width (pow2 of the trie depth)
+PREFIX_WIDTH = 8     # the scheduler's top_k query width
 K = 10
 MAX_FANOUT = 256     # synthetic_csr_trie's hub root at this size
 MAX_POSTINGS = 1 << 17
@@ -145,6 +148,28 @@ def test_rule_search_compressed_compiles(spec):
         spec((N,), sup), spec((N,), conf), spec((N,), lift),
         spec((Q, WIDTH), jnp.int32), spec((Q,), jnp.int32),
     )
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUT_DTYPES))
+def test_prefix_descent_compiles(spec, layout):
+    """``top_k``'s prefix descent, one jitted program per launch shape."""
+    sup, conf, lift = LAYOUT_DTYPES[layout]
+    nc = N if layout == "plain" else N // 4
+    ec = nc - 1
+
+    def i32(*shape):
+        return spec(shape, jnp.int32)
+
+    span = i32(ec) if layout == "compressed" else None
+    dt = DeviceTrie(
+        node_item=i32(N), node_parent=i32(N), node_depth=i32(N),
+        support=spec((N,), sup), confidence=spec((N,), conf),
+        lift=spec((N,), lift), edge_parent=i32(ec), edge_item=i32(ec),
+        edge_child=i32(ec), child_offsets=i32(nc + 1),
+        max_fanout=MAX_FANOUT, dfs_order=i32(N), subtree_size=i32(N),
+        dfs_to_node=i32(N), edge_span=span, edge_tail=span, layout=layout,
+    )
+    _compile(_descend_ranges, dt, i32(Q, PREFIX_WIDTH))
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUT_DTYPES))
