@@ -41,6 +41,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .rank import topk_rank_batch_pallas
 
@@ -142,9 +143,11 @@ def rules_with_pallas(
         node_item, post_lo, post_hi, plos, phis, items,
         role=role, max_postings=int(max_postings),
     )
+    # every query ranks the whole trie [0, N); host arrays, so the rank
+    # program takes them as operands and no eager fill is dispatched
     return topk_rank_batch_pallas(
         support, confidence, lift, depth,
-        jnp.zeros((q,), jnp.int32), jnp.full((q,), n, jnp.int32),
+        np.zeros((q,), np.int32), np.full((q,), n, np.int32),
         k=k, metric=metric, min_depth=min_depth, interpret=interpret,
         block_n=block_n, n_transactions=n_transactions,
         confidence_scale=confidence_scale, lift_scale=lift_scale,

@@ -101,8 +101,9 @@ def _phase(op: str, phase: str, **attrs):
     work and uploads before the launch), ``prefix_ranges`` (``top_k``'s
     host canonicalization and the dispatch of its jitted descent),
     ``kernel`` (the dispatch of the jitted or Pallas program; ``rows``
-    its launch rows) and ``expand`` (the eager gathers after it, back to
-    query rows and node ids)."""
+    its launch rows) and ``expand`` (the dispatch of the jitted gathers
+    after it, back to query rows and node ids: ``_expand_rows``,
+    ``_node_ids`` or ``_gather_rows``, one program per launch shape)."""
     return _scope(f"ops.{op}.{phase}", **attrs)
 
 
@@ -544,6 +545,40 @@ def _pos_to_node(found, pos, dfs_to_node):
     return jnp.where(found, dfs_to_node[jnp.maximum(pos, 0)], -1)
 
 
+# ----------------------------------------------------------------------
+# expand: the gathers after a launch, one jitted program per launch shape
+# ----------------------------------------------------------------------
+def _map_nodes(pos, back):
+    """Kernel position → node id through the ``back`` map (``dfs_to_node``
+    or a posting list's ``item_nodes``), -1 on empty slots.  An empty map
+    (a trie with no rules) is resolved when the program is traced."""
+    if back.shape[0] == 0:
+        return jnp.full_like(pos, -1)
+    return jnp.where(pos >= 0, back[jnp.maximum(pos, 0)], -1)
+
+
+# the ranked ops' node-id map as one dispatch
+_node_ids = jax.jit(_map_nodes)
+
+
+@jax.jit
+def _expand_rows(vals, pos, inv, back):
+    """``rules_with``'s expand in one dispatch: the unique launch rows
+    back to the Q query rows through the inverse map ``inv``, and their
+    positions to node ids.  Integer gathers and selects only, so the
+    rows are bit-identical to the same expressions run eagerly."""
+    vals = vals[inv]
+    pos = pos[inv]
+    return vals, pos, _map_nodes(pos, back)
+
+
+@jax.jit
+def _gather_rows(out, inv):
+    """``rule_search_batch``'s expand in one dispatch: every column of the
+    result dict (a pytree) back to the query rows through ``inv``."""
+    return jax.tree.map(lambda v: v[inv], out)
+
+
 def rule_search(
     trie,                  # DeviceTrie / FrozenTrie
     queries,               # int32 [Q, L] canonical rows (-1 padded)
@@ -782,9 +817,7 @@ def top_k_rules(
             **_dequant_statics(arrays),
         )
     with _phase("top_k", "expand"):
-        node_ids = jnp.where(
-            pos >= 0, arrays["dfs_to_node"][jnp.maximum(pos, 0)], -1
-        )
+        node_ids = _node_ids(pos, arrays["dfs_to_node"])
     return {"values": vals, "node": node_ids, "dfs_pos": pos}
 
 
@@ -1059,12 +1092,7 @@ def rules_with(
             )
         back = arrays["dfs_to_node"]
     with _phase("rules_with", "expand"):
-        vals = vals[inv_j]
-        pos = pos[inv_j]
-        if back.shape[0] == 0:
-            node = jnp.full_like(pos, -1)
-        else:
-            node = jnp.where(pos >= 0, back[jnp.maximum(pos, 0)], -1)
+        vals, pos, node = _expand_rows(vals, pos, inv_j, back)
     return {"values": vals, "node": node, "pos": pos}
 
 
@@ -1238,9 +1266,7 @@ def top_k_rules_batch(
             **_dequant_statics(arrays),
         )
     with _phase("top_k", "expand"):
-        node_ids = jnp.where(
-            pos >= 0, arrays["dfs_to_node"][jnp.maximum(pos, 0)], -1
-        )
+        node_ids = _node_ids(pos, arrays["dfs_to_node"])
     return {"values": vals, "node": node_ids, "dfs_pos": pos}
 
 
@@ -1337,7 +1363,7 @@ def rule_search_batch(
     if inv_j is None:
         return out
     with _phase("rule_search", "expand"):
-        return {key: v[inv_j] for key, v in out.items()}
+        return _gather_rows(out, inv_j)
 
 
 # ----------------------------------------------------------------------

@@ -13,6 +13,9 @@ counterpart (tie order included) and to its jnp oracle:
 - ``prefix_ranges``' jitted descent ≡ the host mirror
   (``host_prefix_ranges``) ≡ the eager column walk, on both layouts,
   compiled once per launch shape,
+- each op's jitted ``expand`` (``_expand_rows`` / ``_node_ids`` /
+  ``_gather_rows``) ≡ the eager gathers it replaced, compiled once per
+  launch shape, with the rank kernel still launched as its own program,
 - everything on BOTH construction engines (``pointer`` freeze and
   ``arrays`` build) — the indexes must answer identically.
 """
@@ -20,6 +23,7 @@ import dataclasses
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 from jax import monitoring
 
@@ -34,6 +38,7 @@ from repro.core.synthetic import (
     synthetic_csr_trie,
 )
 from repro.distributed.trie_sharding import host_prefix_ranges
+from repro.kernels import ops, rank
 from repro.kernels.item_index import ROLES, rules_with_pallas
 from repro.kernels.metrics_inkernel import RANK_METRICS
 from repro.kernels.ops import (
@@ -580,10 +585,8 @@ def test_prefix_ranges_jitted_matches_mirror(store, q, descent_stores):
         assert (cc[hit] == 1).any()
 
 
-def test_prefix_ranges_compiles_once_per_shape(descent_stores):
-    """Two launches of one ``(Q, P)`` shape with different contents run
-    one compiled program: the second compiles nothing."""
-    fz, dt = descent_stores["compressed"]
+def _count_compiles():
+    """``(compiles, stop)``: the backend compiles seen until ``stop()``."""
     compiles = []
     live = [True]
 
@@ -592,6 +595,14 @@ def test_prefix_ranges_compiles_once_per_shape(descent_stores):
             compiles.append(duration)
 
     monitoring.register_event_duration_secs_listener(listen)
+    return compiles, lambda: live.__setitem__(0, False)
+
+
+def test_prefix_ranges_compiles_once_per_shape(descent_stores):
+    """Two launches of one ``(Q, P)`` shape with different contents run
+    one compiled program: the second compiles nothing."""
+    fz, dt = descent_stores["compressed"]
+    compiles, stop = _count_compiles()
     try:
         rng = np.random.RandomState(7)
         a = _descent_matrix(rng, fz, 5, width=7)
@@ -605,4 +616,203 @@ def test_prefix_ranges_compiles_once_per_shape(descent_stores):
         assert _descend_ranges._cache_size() == before + 1
         assert any((x != y).any() for x, y in zip(ra, rb))
     finally:
-        live[0] = False
+        stop()
+
+
+# ----------------------------------------------------------------------
+# expand: the jitted gathers after each launch ≡ the eager ones they
+# replaced (test-only copies below, verbatim), one program per shape
+# ----------------------------------------------------------------------
+def _eager_expand_rows(vals, pos, inv, back):
+    vals = vals[inv]
+    pos = pos[inv]
+    if back.shape[0] == 0:
+        node = jnp.full_like(pos, -1)
+    else:
+        node = jnp.where(pos >= 0, back[jnp.maximum(pos, 0)], -1)
+    return vals, pos, node
+
+
+def _eager_node_ids(pos, back):
+    return jnp.where(pos >= 0, back[jnp.maximum(pos, 0)], -1)
+
+
+def _eager_gather_rows(out, inv):
+    return {key: v[inv] for key, v in out.items()}
+
+
+def _assert_bitwise(got, want, keys, msg=""):
+    for key in keys:
+        g, w = np.asarray(got[key]), np.asarray(want[key])
+        assert g.dtype == w.dtype, f"{msg} {key}"
+        np.testing.assert_array_equal(g, w, err_msg=f"{msg} {key}")
+
+
+def _expand_store(descent_stores, store):
+    """``(trie, n_items)``: the plain layout as its FrozenTrie, the
+    compressed one as its DeviceTrie, the mined paper trie frozen."""
+    fz, dt = descent_stores[store]
+    trie = dt if store == "compressed" else fz
+    return trie, fz.item_offsets.shape[0] - 1
+
+
+def _expand_items(rng, n_items, q):
+    """``q`` items cycling through every kind a launch meets: a few hot
+    items (duplicate-heavy), any item, absent ids past the vocabulary or
+    negative, and the scheduler's ``-2 - i`` pad items."""
+    hot = rng.randint(0, n_items, size=3)
+    items = []
+    for i in range(q):
+        kind = i % 4
+        if kind == 0:
+            items.append(int(hot[rng.randint(0, 3)]))
+        elif kind == 1:
+            items.append(int(rng.randint(0, n_items)))
+        elif kind == 2:
+            items.append(n_items + 5 if i % 8 == 2 else -3)
+        else:
+            items.append(-2 - i)
+    return items
+
+
+@pytest.mark.parametrize("q", [1, 2, 4, 8, 16, 32, 64])
+@pytest.mark.parametrize("role", ROLES)
+@pytest.mark.parametrize("store", ["plain", "compressed", "mined"])
+def test_rules_with_expand_matches_eager(
+    store, role, q, descent_stores, monkeypatch
+):
+    trie, n_items = _expand_store(descent_stores, store)
+    items = _expand_items(np.random.RandomState(q), n_items, q)
+    # k above every match count on the small mined trie: (-inf, -1) tails
+    k = descent_stores["mined"][0].n_nodes + 3 if store == "mined" else 10
+    arrays = item_rank_arrays(trie)
+    got = rules_with(trie, items, role=role, k=k, arrays=arrays)
+    monkeypatch.setattr(ops, "_expand_rows", _eager_expand_rows)
+    want = rules_with(trie, items, role=role, k=k, arrays=arrays)
+    _assert_bitwise(got, want, ("values", "node", "pos"), f"{store}/{role}")
+    node = np.asarray(got["node"])
+    assert node.shape == (q, k)
+    dead = np.array([it < 0 or it >= n_items for it in items])
+    assert (node[dead] == -1).all()
+    if store == "mined":
+        assert (node[:, -3:] == -1).all()
+
+
+@pytest.mark.parametrize("role", ROLES)
+def test_rules_with_expand_oracle_path_matches_eager(
+    role, descent_stores, monkeypatch
+):
+    """The ``use_kernel=False`` oracle path runs the same expand."""
+    trie, n_items = _expand_store(descent_stores, "plain")
+    items = _expand_items(np.random.RandomState(5), n_items, 12)
+    got = rules_with(trie, items, role=role, k=6, use_kernel=False)
+    monkeypatch.setattr(ops, "_expand_rows", _eager_expand_rows)
+    want = rules_with(trie, items, role=role, k=6, use_kernel=False)
+    _assert_bitwise(got, want, ("values", "node", "pos"), role)
+
+
+@pytest.mark.parametrize("q", [1, 8, 64])
+@pytest.mark.parametrize("store", ["plain", "compressed", "mined"])
+def test_top_k_rules_batch_expand_matches_eager(
+    store, q, descent_stores, monkeypatch
+):
+    fz, dt = descent_stores[store]
+    trie = dt if store == "compressed" else fz
+    mat = _descent_matrix(np.random.RandomState(q), fz, q)
+    got = top_k_rules_batch(trie, mat, 7, "lift")
+    monkeypatch.setattr(ops, "_node_ids", _eager_node_ids)
+    want = top_k_rules_batch(trie, mat, 7, "lift")
+    _assert_bitwise(got, want, ("values", "node", "dfs_pos"), store)
+
+
+@pytest.mark.parametrize("dups", [True, False])
+@pytest.mark.parametrize("store", ["plain", "compressed"])
+def test_rule_search_batch_expand_matches_eager(
+    store, dups, descent_stores, monkeypatch
+):
+    """With duplicate rows the batch descends its unique rows and
+    ``_gather_rows`` scatters them back; with none (Q a power of two)
+    no expand runs at all."""
+    fz, dt = descent_stores[store]
+    rng = np.random.RandomState(3)
+    rows = []
+    for _ in range(8):
+        path = _path(fz, int(rng.randint(1, fz.n_nodes)))[:6]
+        rows.append(path + [-1] * (6 - len(path)))
+    queries = np.array(rows, np.int32)
+    ant_len = np.array(
+        [max(int((r >= 0).sum()) - 1, 0) for r in queries], np.int32
+    )
+    if dups:
+        pick = np.array([0, 1, 0, 2, 1, 0, 3, 3, 2])
+        queries, ant_len = queries[pick], ant_len[pick]
+    calls = []
+    jitted = ops._gather_rows
+
+    def spy(out, inv):
+        calls.append(inv.shape)
+        return jitted(out, inv)
+
+    monkeypatch.setattr(ops, "_gather_rows", spy)
+    got = rule_search_batch(dt, queries, ant_len=ant_len)
+    assert len(calls) == (1 if dups else 0)
+    monkeypatch.setattr(ops, "_gather_rows", _eager_gather_rows)
+    want = rule_search_batch(dt, queries, ant_len=ant_len)
+    keys = ("found", "node", "support", "confidence", "lift")
+    assert sorted(got) == sorted(want) == sorted(keys)
+    _assert_bitwise(got, want, keys, store)
+    assert np.asarray(got["found"]).all()
+
+
+def test_expand_compiles_once_per_shape(descent_stores):
+    """A second launch of one ``(U_pad, Q, k)`` shape with other contents
+    compiles nothing; each op's expand holds one program for it."""
+    fz, dt = descent_stores["compressed"]
+    n_items = fz.item_offsets.shape[0] - 1
+    arrays = item_rank_arrays(dt)
+    compiles, stop = _count_compiles()
+    try:
+        # distinct items: U_pad == Q, as on the served path
+        before = ops._expand_rows._cache_size()
+        a = rules_with(dt, [0, 1, 2, -2], role="any", k=9, arrays=arrays)
+        first, progs = len(compiles), ops._expand_rows._cache_size()
+        assert progs - before <= 1
+        b = rules_with(dt, [3, 4, n_items - 1, -5], role="any", k=9,
+                       arrays=arrays)
+        assert len(compiles) == first
+        assert ops._expand_rows._cache_size() == progs
+        assert (np.asarray(a["node"]) != np.asarray(b["node"])).any()
+
+        mats = [_descent_matrix(np.random.RandomState(s), fz, 4, width=5)
+                for s in (1, 2)]
+        top_k_rules_batch(dt, mats[0], 9, "support")
+        first, progs = len(compiles), ops._node_ids._cache_size()
+        top_k_rules_batch(dt, mats[1], 9, "support")
+        assert len(compiles) == first
+        assert ops._node_ids._cache_size() == progs
+    finally:
+        stop()
+
+
+@pytest.mark.parametrize("store", ["plain", "compressed"])
+def test_rank_kernel_stays_its_own_program(store, descent_stores, monkeypatch):
+    """``rank_roofline`` reads the rank kernel inside the program of
+    ``rank._topk_rank_batch_impl``: a ``rules_with`` launch (both of its
+    paths on the plain layout) and a ``top_k`` launch still dispatch that
+    program themselves, with concrete operands, never traced inside an
+    outer program."""
+    fz, dt = descent_stores[store]
+    trie = dt if store == "compressed" else fz
+    calls = []
+    impl = rank._topk_rank_batch_impl
+
+    def spy(support, *args, **kw):
+        calls.append(isinstance(support, jax.core.Tracer))
+        return impl(support, *args, **kw)
+
+    monkeypatch.setattr(rank, "_topk_rank_batch_impl", spy)
+    for role in ROLES:
+        rules_with(trie, [0, 1, -2, -3], role=role, k=5)
+    top_k_rules_batch(trie, _descent_matrix(
+        np.random.RandomState(0), fz, 4), 5)
+    assert calls == [False] * (len(ROLES) + 1)

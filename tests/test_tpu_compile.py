@@ -23,7 +23,12 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.array_trie import DeviceTrie
 from repro.kernels.item_index import ROLES, rules_with_pallas
-from repro.kernels.ops import _descend_ranges
+from repro.kernels.ops import (
+    _descend_ranges,
+    _expand_rows,
+    _gather_rows,
+    _node_ids,
+)
 from repro.kernels.rank import topk_rank_batch_pallas
 from repro.kernels.rule_search import rule_search_fused, rule_search_span
 from repro.kernels.support_count import support_count_pallas
@@ -170,6 +175,25 @@ def test_prefix_descent_compiles(spec, layout):
         dfs_to_node=i32(N), edge_span=span, edge_tail=span, layout=layout,
     )
     _compile(_descend_ranges, dt, i32(Q, PREFIX_WIDTH))
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUT_DTYPES))
+def test_expand_compiles(spec, layout):
+    """The ops' expand programs: ``rules_with``'s row and node-id gathers
+    (through the posting list's node map on the plain layout, through
+    ``dfs_to_node`` on the compressed one), ``top_k``'s node-id map and
+    ``rule_search_batch``'s row gather over its result dict."""
+    back = spec((MAX_POSTINGS if layout == "plain" else N,), jnp.int32)
+    vals, pos = spec((Q, K), jnp.float32), spec((Q, K), jnp.int32)
+    _compile(_expand_rows, vals, pos, spec((Q,), jnp.int32), back)
+    _compile(_node_ids, pos, spec((N,), jnp.int32))
+    out = {
+        "found": spec((Q,), jnp.bool_), "node": spec((Q,), jnp.int32),
+        "support": spec((Q,), jnp.float32),
+        "confidence": spec((Q,), jnp.float32),
+        "lift": spec((Q,), jnp.float32),
+    }
+    _compile(_gather_rows, out, spec((Q,), jnp.int32))
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUT_DTYPES))
